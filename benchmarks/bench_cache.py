@@ -25,8 +25,7 @@ def main(n=4000, dim=32):
         results[("policy", policy)] = s
         csv_row(f"fig9_policy_{policy}", 1e6 / max(s["search_qps"], 1e-9),
                 recall=s["recall"], search_qps=s["search_qps"],
-                p99_ms=s["search_p99_ms"], miss_rate=s.get("miss_rate", 0),
-                modeled_us=s.get("modeled_us", 0))
+                p99_ms=s["search_p99_ms"], miss_rate=s.get("miss_rate", 0))
     # Fig 10: memory-ratio sweep (cache slots as % of live set ~2000)
     for ratio in (0.2, 0.4, 0.6, 0.8, 1.0):
         slots = int(2000 * ratio)

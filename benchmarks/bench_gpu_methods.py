@@ -32,8 +32,7 @@ def run_method(name, dim, data, queries, cache_slots):
     s = idx.stats()
     return {"qps": len(queries) / dt, "recall": rec,
             "miss_rate": s["miss_rate"],
-            "transfers": s["transfers"],
-            "modeled_us": s["modeled_us_per_access"]}
+            "transfers": s["transfers"]}
 
 
 def main(n=5000, dim=32):

@@ -120,7 +120,6 @@ def run_workload(index, workload, k=10, name=None, max_steps=None) -> RunMetrics
     if hasattr(index, "stats"):
         s = index.stats()
         m.extra["miss_rate"] = s.get("miss_rate", 0.0)
-        m.extra["modeled_us"] = s.get("modeled_us_per_access", 0.0)
     if hasattr(index, "rebuilds"):
         m.extra["rebuilds"] = index.rebuilds
     return m

@@ -2,15 +2,17 @@
 
 MUST run as its own process (device count is locked at first jax import):
     PYTHONPATH=src python examples/distributed_search.py
+It runs on eight virtual CPU devices and leaves an attached TPU alone;
+``chip_smoke.py --chips 4`` drives the same sharded search on real chips.
 """
 import os
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax                  # noqa: E402
 import jax.numpy as jnp     # noqa: E402
 import numpy as np          # noqa: E402
 
-from repro import compat                                      # noqa: E402
 from repro.core.build import build_graph                      # noqa: E402
 from repro.core.distributed import make_distributed_search    # noqa: E402
 from repro.core.search import brute_force_topk, recall_at_k   # noqa: E402
@@ -42,7 +44,7 @@ def main():
         "f_recent": np.zeros((N,), np.float32),
     }
     Q = rng.normal(size=(64, D)).astype(np.float32)
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jidx = {k: jnp.asarray(v) for k, v in idx.items()}
         ids, dists = jax.jit(step)(jidx, jnp.asarray(Q), jax.random.PRNGKey(0))
         ids.block_until_ready()

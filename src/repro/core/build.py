@@ -9,6 +9,8 @@ from sampled inter-partition distance blocks, then rank-based reordering
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,24 +20,33 @@ from repro.core.types import (GraphState, IndexState, init_cache_state,
 
 
 def pairwise_l2(a, b):
-    """Squared L2 distances [n, m] via the GEMM form ||a||² - 2ab + ||b||²."""
+    """Squared L2 distances [n, m] via the GEMM form ||a||² - 2ab + ||b||²,
+    at fp32 precision (the TPU default rounds matmul operands to bf16)."""
     a2 = jnp.sum(a * a, axis=1, keepdims=True)
     b2 = jnp.sum(b * b, axis=1, keepdims=True)
-    return a2 - 2.0 * (a @ b.T) + b2.T
+    ab = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+    return a2 - 2.0 * ab + b2.T
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _knn_block(vectors, start, block, k):
+    """Top-k neighbor ids of the rows ``start + [0, len(block))``."""
+    d = pairwise_l2(block, vectors)
+    rows = jnp.arange(block.shape[0])
+    d = d.at[rows, start + rows].set(jnp.inf)          # exclude self
+    return jax.lax.top_k(-d, k)[1]
 
 
 def _exact_knn(vectors, k, chunk=2048):
-    """Top-k neighbor ids for every row (excluding self). Chunked GEMMs.
-    If the dataset has fewer than k+1 rows, pads with -1."""
+    """Top-k neighbor ids for every row (excluding self). Chunked GEMMs,
+    each distance block held to 2^28 entries (1 GiB). If the dataset has
+    fewer than k+1 rows, pads with -1."""
+    vectors = jnp.asarray(vectors, jnp.float32)
     n = vectors.shape[0]
     k_eff = max(1, min(k, n - 1))
-    ids = []
-    for s in range(0, n, chunk):
-        d = pairwise_l2(vectors[s:s + chunk], vectors)
-        rows = jnp.arange(s, min(s + chunk, n)) - s
-        d = d.at[rows, jnp.arange(s, min(s + chunk, n))].set(jnp.inf)
-        _, idx = jax.lax.top_k(-d, k_eff)
-        ids.append(idx)
+    chunk = max(1, min(chunk, (1 << 28) // max(n, 1)))
+    ids = [_knn_block(vectors, s, vectors[s:s + chunk], k_eff)
+           for s in range(0, n, chunk)]
     out = jnp.concatenate(ids, axis=0)
     if k_eff < k:
         out = jnp.concatenate(
@@ -74,21 +85,44 @@ def rank_based_reorder(cand_ids, cand_dists, nbrs, degree):
 
 
 def _add_reverse_edges(nbrs_np: np.ndarray, n: int, rng: np.random.Generator):
-    """Host-side exact reverse-edge pass (build time): for each edge u->v add
-    v->u if v has a free slot, else replace a random slot with prob 1/2."""
+    """Host-side reverse-edge pass (build time), vectorized: for each edge
+    u->v whose reverse v->u is not already in v's row, add v->u into v's
+    next free slot if it has one, else replace a uniformly random slot
+    with probability 1/2. Rows take their reverse edges in ascending u
+    order, so when several land on one slot the largest u wins — the
+    order a sequential pass over u would apply them in. Membership is
+    tested against the rows as built (before this pass)."""
     R = nbrs_np.shape[1]
-    for u in range(n):
-        for v in nbrs_np[u]:
-            if v < 0:
-                continue
-            row = nbrs_np[v]
-            if u in row:
-                continue
-            free = np.where(row < 0)[0]
-            if free.size:
-                row[free[0]] = u
-            elif rng.random() < 0.5:
-                row[rng.integers(R)] = u
+    src = nbrs_np[:n].reshape(-1).astype(np.int64)
+    u = np.repeat(np.arange(n, dtype=np.int64), R)[src >= 0]
+    src = src[src >= 0]
+    if not len(src):
+        return nbrs_np
+    # candidate reverse edges keyed v·n + u: sorted, they run row by row
+    # in ascending u; drop those already present (mutual neighbors)
+    rev = np.sort(src * n + u)
+    edges = np.sort(u * n + src)
+    pos = np.minimum(np.searchsorted(edges, rev), len(edges) - 1)
+    rev = rev[edges[pos] != rev]
+    v, u = rev // n, rev % n
+    starts = np.flatnonzero(np.r_[True, v[1:] != v[:-1]])
+    rank = np.arange(len(v)) - np.repeat(starts, np.diff(np.r_[starts,
+                                                              len(v)]))
+    free = nbrs_np < 0
+    fill = rank < free.sum(1)[v]
+    # the rank-th free slot of row v (free slots first, in slot order)
+    free_pos = np.argsort(~free, axis=1, kind="stable")
+    nbrs_np[v[fill], free_pos[v[fill], rank[fill]]] = u[fill]
+    u, v = u[~fill], v[~fill]
+    take = rng.random(len(u)) < 0.5
+    slot = rng.integers(R, size=len(u))
+    u, v, slot = u[take], v[take], slot[take]
+    # one writer per (row, slot): the largest u, which a pass in
+    # ascending u order would have written last
+    key = np.sort((v * R + slot) * n + u)
+    cell = key // n
+    last = key[np.r_[cell[1:] != cell[:-1], True]]
+    nbrs_np[last // n // R, last // n % R] = last % n
     return nbrs_np
 
 

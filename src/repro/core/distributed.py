@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.search import _frontier_search
 from repro.core.types import (CacheState, GraphState, SearchParams,
                               init_cache_state)
@@ -98,7 +97,7 @@ def make_distributed_search(mesh, sp: SearchParams,
         mul = 1
         for ax in reversed(present):
             shard_lin = shard_lin + jax.lax.axis_index(ax) * mul
-            mul = mul * compat.axis_size(ax)
+            mul = mul * jax.lax.axis_size(ax)
         offset = shard_lin.astype(jnp.int32) * n_local
 
         graph = GraphState(
@@ -132,8 +131,8 @@ def make_distributed_search(mesh, sp: SearchParams,
             all_d = -nd
         return all_ids, all_d
 
-    return compat.shard_map(step, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False)
+    return jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def analytical_search_flops(sp: SearchParams, batch, dim, degree):

@@ -1175,7 +1175,6 @@ class SVFusionEngine:
         else:
             d["n"] = int(st.graph.n)
             d["alive"] = int(st.graph.alive.sum())
-            dim = st.graph.vectors.shape[1]
         d["consolidations"] = self._consolidations
         if self._coalescer is not None:
             c = self._coalescer
@@ -1189,15 +1188,6 @@ class SVFusionEngine:
             # queue depths, shed / deadline-miss counters, pressure and
             # the current degradation level (core/slo.py)
             d["slo"] = c.tier.stats()
-        # modeled per-access time on v5e (DESIGN.md §2): this machine has
-        # one physical tier, so tier economics are reported via the
-        # calibrated cost model applied to observed hit/miss/transfer counts
-        from repro.core.calibrate import v5e_constants
-        cm = v5e_constants(dim)
-        acc = max(d["accesses"], 1)
-        modeled = (d["hits"] * cm.t_fast + d["cpu_computed"] * cm.t_slow
-                   + d["transfers"] * cm.t_transfer)
-        d["modeled_us_per_access"] = modeled / acc * 1e6
         return d
 
     def close(self):

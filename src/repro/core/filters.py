@@ -21,10 +21,9 @@ lane** instead:
   tag field (bit v set = value v allowed; unconstrained = all ones) and
   fp32 bound vectors per numeric field (unconstrained = ∓inf). One
   jitted pass over the attribute mirror yields a per-id boolean mask
-  that the executor ANDs into its existing alive/-1 invalid-lane
-  masking (``jnp.where(valid, d, +inf)``), so filtered-out candidates
-  never enter the pool — the same composition the ``l2_gather`` /
-  ``pq_adc`` kernels already honor for id -1.
+  that admits candidates to the executor's result pool; the walk itself
+  still traverses nodes that fail the filter, which keeps the graph
+  connected at low selectivity (``search.result_pool``).
 * ``estimate_selectivity`` — the cheap host-side sample the engine uses
   at admission to route low-selectivity queries to the brute-force ADC
   fallback (``search.search_tiered``): below the threshold a graph walk
@@ -254,10 +253,10 @@ def _device_pass(tags_j, nums_j, tag_masks, num_lo, num_hi):
 
 def device_pass_mask(attrs, cf: CompiledFilter):
     """Per-id predicate mask evaluated ON DEVICE against the attribute
-    store's epoch-synced mirror: bool [capacity] device array the
-    executor ANDs with ``alive`` before the usual
-    ``where(valid, d, +inf)`` masking. One tiny jitted dispatch per
-    search batch; the fused round loop then just gathers from it."""
+    store's epoch-synced mirror: bool [capacity] device array that, with
+    ``alive``, admits candidates to the fused dispatch's result pool. One
+    tiny jitted dispatch per search batch; the fused round loop then
+    just gathers from it."""
     tags_j, nums_j = attrs.synced()
     return _device_pass(tags_j, nums_j, jnp.asarray(cf.tag_masks),
                         jnp.asarray(cf.num_lo), jnp.asarray(cf.num_hi))

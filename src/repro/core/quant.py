@@ -74,6 +74,7 @@ def _sqdist_to_centroids(sub, cents):
     sub [..., m, dsub] vs cents [m, K, dsub] -> [..., m, K]."""
     return (jnp.sum(sub * sub, -1)[..., None]
             - 2.0 * jnp.einsum("...md,mkd->...mk", sub, cents,
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
             + jnp.sum(cents * cents, -1))
 
@@ -94,6 +95,7 @@ def _train(vectors, key, m: int, k: int, iters: int):
         onehot = jax.nn.one_hot(assign, k, dtype=jnp.float32)  # [n, m, k]
         cnt = onehot.sum(0)                                    # [m, k]
         sums = jnp.einsum("nmk,nmd->mkd", onehot, sub,
+                          precision=jax.lax.Precision.HIGHEST,
                           preferred_element_type=jnp.float32)
         # empty clusters keep their old centroid (never NaN-divide)
         new = jnp.where(cnt[..., None] > 0,

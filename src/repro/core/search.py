@@ -188,7 +188,32 @@ def init_pool(entry_ids, entry_d, id_bound=None):
             jnp.zeros(entry_ids.shape, bool))
 
 
-def _run_fused_rounds(state, r_stop, beam, id_bound, row_fn, dist_fn):
+def result_pool(ids, d, keep, id_bound=None):
+    """Filtered search's result pool [B, L] (ids, dists), seeded from an
+    entry or candidate batch: only lanes with ``keep`` set (alive AND
+    passing the filter) enter it, while the traversal pool goes on
+    walking through nodes that fail the filter — a graph restricted to
+    the passing nodes falls apart at low selectivity. None when the
+    search is unfiltered (``keep`` None)."""
+    if keep is None:
+        return None
+    r_ids, r_d, _ = init_pool(ids, jnp.where(keep, d, INF), id_bound)
+    return r_ids, r_d
+
+
+def merge_result(res, cand_ids, cand_d, keep, id_bound=None):
+    """Merge one round's kept candidates into the result pool (identity
+    when unfiltered)."""
+    if res is None:
+        return None
+    r_ids, r_d, _ = merge_round(res[0], res[1], jnp.zeros(res[0].shape, bool),
+                                cand_ids, jnp.where(keep, cand_d, INF),
+                                id_bound)
+    return r_ids, r_d
+
+
+def _run_fused_rounds(state, r_stop, beam, id_bound, row_fn, dist_fn,
+                      keep_fn=None):
     """The ONE fused multi-round executor core both arms share: a
     ``lax.while_loop`` running row gather -> distance -> topk merge ->
     next-frontier select entirely on device, round after round, until the
@@ -196,7 +221,8 @@ def _run_fused_rounds(state, r_stop, beam, id_bound, row_fn, dist_fn):
     recompile), the pool runs dry, or a row lookup stalls.
 
     ``state`` carry: (r, pool_ids, pool_d, visited, curr, acc_ids
-    [B, rounds, C], acc_hit, iters [B], stall). The frontier ``curr`` is
+    [B, rounds, C], acc_hit, iters [B], res, stall), ``res`` the filtered
+    search's result pool or None. The frontier ``curr`` is
     selected at the END of each body (entry select happens outside), so
     the loop condition reads residual work straight off the idle-lane
     sentinel — same gating as the old device-arm loop, where the select
@@ -213,14 +239,15 @@ def _run_fused_rounds(state, r_stop, beam, id_bound, row_fn, dist_fn):
     never a wrong merge.
 
     ``dist_fn(nb [B, C]) -> (d, hit, valid)`` scores a flattened
-    candidate batch, +inf on invalid lanes.
+    candidate batch, +inf on invalid lanes. ``keep_fn(nb) -> [B, C]``
+    (filtered search) marks the lanes that may enter the result pool.
     """
     def cond(s):
-        r, _ids, _d, _vis, curr, _ai, _ah, _it, stall = s
+        r, _ids, _d, _vis, curr, _ai, _ah, _it, _res, stall = s
         return (r < r_stop) & ~stall & (curr >= 0).any()
 
     def body(s):
-        r, ids, dists, visited, curr, acc_ids, acc_hit, iters, _ = s
+        r, ids, dists, visited, curr, acc_ids, acc_hit, iters, res, _ = s
         B, C = acc_ids.shape[0], acc_ids.shape[2]
         nb, res_ok = row_fn(curr)                     # [B, beam, R]
         stall = ((curr >= 0) & ~res_ok).any()
@@ -229,15 +256,19 @@ def _run_fused_rounds(state, r_stop, beam, id_bound, row_fn, dist_fn):
         active = (curr >= 0).any(1)                   # [B]
         ids2, d2, vis2 = merge_round(ids, dists, visited, nb, d, id_bound)
         curr2, vis2 = select_frontier(ids2, d2, vis2, beam)
+        if keep_fn is not None:
+            res2 = merge_result(res, nb, d, keep_fn(nb) & valid, id_bound)
+        else:
+            res2 = res
         new = (r + 1, ids2, d2, vis2, curr2,
                acc_ids.at[:, r].set(jnp.where(valid, nb, -1)),
                acc_hit.at[:, r].set(hit & valid),
-               iters + active.astype(jnp.int32))
-        old = (r, ids, dists, visited, curr, acc_ids, acc_hit, iters)
+               iters + active.astype(jnp.int32), res2)
+        old = (r, ids, dists, visited, curr, acc_ids, acc_hit, iters, res)
         # a stalled round is discarded atomically: every carry leaf keeps
         # its pre-round value so the host re-enters at the same state
-        return tuple(jnp.where(stall, o, n)
-                     for o, n in zip(old, new)) + (stall,)
+        return jax.tree.map(lambda o, n: jnp.where(stall, o, n),
+                            old, new) + (stall,)
 
     return jax.lax.while_loop(cond, body, state)
 
@@ -293,8 +324,8 @@ def _frontier_search(graph: GraphState, cache: CacheState, queries, entries,
     state0 = (jnp.int32(0), pool_ids0, pool_d0, visited0, curr0,
               jnp.full((B, rounds, C), -1, jnp.int32),
               jnp.zeros((B, rounds, C), bool),
-              jnp.zeros((B,), jnp.int32), jnp.bool_(False))
-    (_, ids, dists, _, _, acc_ids, acc_hit, iters, _) = _run_fused_rounds(
+              jnp.zeros((B,), jnp.int32), None, jnp.bool_(False))
+    (_, ids, dists, _, _, acc_ids, acc_hit, iters, _, _) = _run_fused_rounds(
         state0, rounds, beam, id_bound, row_fn, dist_fn)
 
     topk_ids = jnp.where(jnp.isfinite(dists[:, :sp.k]), ids[:, :sp.k], -1)
@@ -335,32 +366,37 @@ def _batch_sqdist(x, q):
     """[B, C, D] gathered rows vs [B, D] queries -> [B, C] fp32 distances.
     Expansion form (‖x‖² − 2x·q + ‖q‖²): the inner product maps onto the
     batched-matmul path, ~1.4x the subtract-then-reduce einsum on CPU."""
-    xq = jnp.matmul(x, q[:, :, None],
+    hi = jax.lax.Precision.HIGHEST    # fp32-exact on TPU, whose default
+    #                                   rounds matmul operands to bf16
+    xq = jnp.matmul(x, q[:, :, None], precision=hi,
                     preferred_element_type=jnp.float32)[..., 0]
-    x2 = jnp.einsum("bcd,bcd->bc", x, x,
+    x2 = jnp.einsum("bcd,bcd->bc", x, x, precision=hi,
                     preferred_element_type=jnp.float32)
-    q2 = jnp.einsum("bd,bd->b", q, q,
+    q2 = jnp.einsum("bd,bd->b", q, q, precision=hi,
                     preferred_element_type=jnp.float32)[:, None]
     return x2 - 2.0 * xq + q2
 
 
 @partial(jax.jit, static_argnames=("beam", "id_bound"))
 def _tiered_entry_dispatch(entry_ids, entry_vecs, entry_valid, queries,
-                           beam, id_bound):
+                           beam, id_bound, entry_keep=None):
     """Entry-pool distances + dedup + sort + first frontier selection:
     the first of the per-round dispatches (shares the executor core with
     the device arm). Pool state stays device-resident across rounds; only
-    the tiny [B, beam] frontier id matrix crosses back to the host."""
+    the tiny [B, beam] frontier id matrix crosses back to the host.
+    ``entry_keep`` (filtered search) seeds the result pool."""
     d = _batch_sqdist(entry_vecs, queries)
     d = jnp.where(entry_valid, d, INF)
     pool_ids, pool_d, visited = init_pool(entry_ids, d, id_bound)
     curr, visited = select_frontier(pool_ids, pool_d, visited, beam)
-    return pool_ids, pool_d, visited, curr
+    res = result_pool(entry_ids, d, entry_keep, id_bound)
+    return pool_ids, pool_d, visited, curr, res
 
 
 @partial(jax.jit, static_argnames=("beam", "id_bound"))
 def _tiered_round_dispatch(pool_ids, pool_d, visited, cand_ids, uniq_vecs,
-                           cand_inv, cand_valid, queries, beam, id_bound):
+                           cand_inv, cand_valid, queries, beam, id_bound,
+                           res=None, cand_keep=None):
     """ONE jitted gather+distance+topk-merge(+next frontier selection)
     dispatch covering every hop in the round's beam — the tiered arm of
     the shared executor. The host ships each round's *unique* vectors
@@ -375,7 +411,8 @@ def _tiered_round_dispatch(pool_ids, pool_d, visited, cand_ids, uniq_vecs,
     pool_ids, pool_d, visited = merge_round(pool_ids, pool_d, visited,
                                             cand_ids, d, id_bound)
     curr, visited = select_frontier(pool_ids, pool_d, visited, beam)
-    return pool_ids, pool_d, visited, curr
+    res = merge_result(res, cand_ids, d, cand_keep, id_bound)
+    return pool_ids, pool_d, visited, curr, res
 
 
 # ---------------------------------------------------------------------------
@@ -387,7 +424,7 @@ def _tiered_round_dispatch(pool_ids, pool_d, visited, cand_ids, uniq_vecs,
 
 @partial(jax.jit, static_argnames=("beam", "id_bound"))
 def _pq_entry_dispatch(entry_ids, entry_valid, codes, centroids, queries,
-                       beam, id_bound):
+                       beam, id_bound, entry_keep=None):
     """Entry-pool ADC scan + dedup + sort + first frontier selection —
     the code-lane twin of ``_tiered_entry_dispatch``. Builds the per-query
     ADC lookup tables in the same dispatch and returns them for reuse by
@@ -397,12 +434,13 @@ def _pq_entry_dispatch(entry_ids, entry_valid, codes, centroids, queries,
     d = jnp.where(entry_valid, d, INF)
     pool_ids, pool_d, visited = init_pool(entry_ids, d, id_bound)
     curr, visited = select_frontier(pool_ids, pool_d, visited, beam)
-    return pool_ids, pool_d, visited, curr, lut
+    res = result_pool(entry_ids, d, entry_keep, id_bound)
+    return pool_ids, pool_d, visited, curr, lut, res
 
 
 @partial(jax.jit, static_argnames=("beam", "id_bound"))
 def _pq_round_dispatch(pool_ids, pool_d, visited, cand_ids, cand_valid,
-                       codes, lut, beam, id_bound):
+                       codes, lut, beam, id_bound, res=None, cand_keep=None):
     """ONE jitted code-gather + ADC + topk-merge (+ next frontier
     selection) dispatch covering every hop in the round's beam. Unlike
     the exact lane's ``_tiered_round_dispatch`` the host ships NOTHING
@@ -413,13 +451,14 @@ def _pq_round_dispatch(pool_ids, pool_d, visited, cand_ids, cand_valid,
     pool_ids, pool_d, visited = merge_round(pool_ids, pool_d, visited,
                                             cand_ids, d, id_bound)
     curr, visited = select_frontier(pool_ids, pool_d, visited, beam)
-    return pool_ids, pool_d, visited, curr
+    res = merge_result(res, cand_ids, d, cand_keep, id_bound)
+    return pool_ids, pool_d, visited, curr, res
 
 
 @partial(jax.jit, static_argnames=("beam", "id_bound"))
 def _pq_fused_dispatch(pool_ids, pool_d, visited, curr, r, acc_ids,
                        topo_rows, topo_h2s, codes, lut, alive, r_stop,
-                       beam, id_bound):
+                       beam, id_bound, res=None, fmask=None):
     """K consecutive PQ rounds in ONE jitted dispatch — the tiered arm's
     instantiation of the shared ``_run_fused_rounds`` core. While the
     frontier stays inside the device-resident topology cache the loop
@@ -433,7 +472,9 @@ def _pq_fused_dispatch(pool_ids, pool_d, visited, curr, r, acc_ids,
     Bit-parity with the per-round ``_pq_round_dispatch`` path holds by
     construction: the cached rows equal the store rows (epoch-fenced),
     the candidate mask/merge/select are the same shared core ops in the
-    same order, and a stalled round is discarded wholesale."""
+    same order, and a stalled round is discarded wholesale. ``fmask``
+    (filtered search: the device pass mask over ids) admits candidates
+    to the result pool ``res``."""
     def row_fn(c):
         nb = gather_rows(topo_rows, topo_h2s, c)       # [B, beam, R]
         slot = topo_h2s[jnp.clip(c, 0)]
@@ -446,19 +487,20 @@ def _pq_fused_dispatch(pool_ids, pool_d, visited, curr, r, acc_ids,
         # hit flags are derived from exact-cache residency at the end
         return jnp.where(valid, d, INF), jnp.zeros(nb.shape, bool), valid
 
+    keep_fn = None if fmask is None else (lambda nb: fmask[jnp.clip(nb, 0)])
     B = pool_ids.shape[0]
     state0 = (r, pool_ids, pool_d, visited, curr, acc_ids,
               jnp.zeros(acc_ids.shape, bool), jnp.zeros((B,), jnp.int32),
-              jnp.bool_(False))
-    (r1, ids1, d1, vis1, curr1, acc1, _, _, _) = _run_fused_rounds(
-        state0, r_stop, beam, id_bound, row_fn, dist_fn)
-    return ids1, d1, vis1, curr1, r1, acc1
+              res, jnp.bool_(False))
+    (r1, ids1, d1, vis1, curr1, acc1, _, _, res1, _) = _run_fused_rounds(
+        state0, r_stop, beam, id_bound, row_fn, dist_fn, keep_fn)
+    return ids1, d1, vis1, curr1, r1, acc1, res1
 
 
 def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
                       codes_epoch, lut, pool_ids, pool_d, visited, curr_j,
                       beam, rounds, id_bound, fused_rounds, stage_width=0,
-                      alive_j=None):
+                      res=None, hmask=None, fmask_j=None):
     """Host fallback shell around ``_pq_fused_dispatch``: the executor's
     round loop when a topology cache is attached. Steady state is ONE
     fused dispatch covering every remaining round (dispatches/query drops
@@ -478,8 +520,12 @@ def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
     stages their store rows, so a future miss-exit's delta fetch is a
     memo hit instead of disk IO.
 
+    Filtered search threads its result pool ``res`` through, admitting
+    candidates by the device pass mask ``fmask_j`` in fused rounds and by
+    its host twin ``hmask`` in per-round fallback rounds.
+
     Returns (pool_ids, pool_d, acc [B, rounds, C] np.int32, rounds
-    executed, dispatches issued, topo hits, topo misses)."""
+    executed, dispatches issued, topo hits, topo misses, res)."""
     B = int(pool_ids.shape[0])
     R = topo.degree
     C = beam * R
@@ -520,12 +566,9 @@ def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
             out = _pq_fused_dispatch(
                 pool_ids, pool_d, visited, curr_j,
                 jnp.asarray(r, jnp.int32), acc_j, rows_j, h2s_j, codes_j,
-                lut,
-                # filtered search supplies the device-resident composite
-                # mask (alive AND the predicate evaluated against the
-                # attribute mirror); unfiltered ships the live bitset
-                alive_j if alive_j is not None else jnp.asarray(alive),
-                jnp.asarray(min(r + K, rounds), jnp.int32), beam, id_bound)
+                lut, jnp.asarray(alive),
+                jnp.asarray(min(r + K, rounds), jnp.int32), beam, id_bound,
+                res, fmask_j)
             dispatches += 1
             if spec is not None:
                 # topology prefetch one cache-miss ahead, overlapping the
@@ -540,7 +583,7 @@ def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
                     if nxt.size > w:
                         nxt = nxt[np.argpartition(-f_lam[nxt], w - 1)[:w]]
                     spec.stage(nxt)
-            pool_ids, pool_d, visited, curr_j, r_j, acc_j = out
+            pool_ids, pool_d, visited, curr_j, r_j, acc_j, res = out
             curr = np.asarray(curr_j)         # the shell's only sync point
             new_r = int(r_j)
             # a dispatch that advanced no round means residency changed
@@ -556,9 +599,11 @@ def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
             nb[okm] = cached_rows[np.searchsorted(ucur, curr[okm])]
             nb = nb.reshape(B, C)
             valid = (nb >= 0) & alive[np.clip(nb, 0, None)]
-            pool_ids, pool_d, visited, curr_j = _pq_round_dispatch(
+            keep = None if hmask is None else \
+                jnp.asarray(valid & hmask[np.clip(nb, 0, None)])
+            pool_ids, pool_d, visited, curr_j, res = _pq_round_dispatch(
                 pool_ids, pool_d, visited, jnp.asarray(nb),
-                jnp.asarray(valid), codes_j, lut, beam, id_bound)
+                jnp.asarray(valid), codes_j, lut, beam, id_bound, res, keep)
             dispatches += 1
             if acc_np is None:
                 acc_np = np.full((B, rounds, C), -1, np.int32)
@@ -570,7 +615,7 @@ def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
     acc = np.array(acc_j)   # copy: jax buffers are read-only views
     if fb_rounds:   # overlay host-logged fallback rounds onto the device log
         acc[:, fb_rounds] = acc_np[:, fb_rounds]
-    return pool_ids, pool_d, acc, r, dispatches, hits, misses
+    return pool_ids, pool_d, acc, r, dispatches, hits, misses, res
 
 
 @partial(jax.jit, static_argnames=("depth",))
@@ -898,6 +943,16 @@ def effective_rerank_depth(rerank_depth: int, k: int, pool: int) -> int:
     return pool if rerank_depth <= 0 else max(k, min(rerank_depth, pool))
 
 
+def filtered_walk_pool(pool: int, k: int, selectivity: float) -> int:
+    """Traversal pool width for a filtered graph walk. The k nearest
+    passing nodes sit among roughly the k/selectivity nearest of all, and
+    a walk whose pool holds fewer runs dry before it has scored them:
+    the next power of two >= k/selectivity, at least ``pool``, at most
+    4·``pool`` (so at most three widths ever compile)."""
+    need = int(np.ceil(k / max(selectivity, 1e-6)))
+    return min(max(pool, 1 << (need - 1).bit_length()), 4 * pool)
+
+
 def _filtered_brute_force(backend, queries, qj, hmask, alive_snap, sp,
                           pq, rerank_depth, h2d, cache_vec, f_lam,
                           filter_sel) -> TieredSearchResult:
@@ -1019,11 +1074,13 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
     ``filter``: a ``filters.FilterSpec`` metadata predicate — requires an
     attached ``backend.attrs`` store. Selectivity is sampled at admission
     (``filter_sample`` ids, deterministic in ``seed``): at or above
-    ``filter_fallback_selectivity`` the predicate joins the executor's
-    invalid-lane masking (filtered-out candidates never enter the pool,
-    both arms); below it the query routes to the brute-force scan over
-    the matched set (``_filtered_brute_force``). The chosen path and the
-    measured selectivity ride the result (``filter_path`` /
+    ``filter_fallback_selectivity`` the graph walk runs over every live
+    node while a separate result pool keeps only the live nodes that pass
+    the predicate (``result_pool``/``merge_result``, both arms; the
+    results and the PQ re-rank come from it); below it the query routes
+    to the brute-force scan over the matched set
+    (``_filtered_brute_force``). The chosen path and the measured
+    selectivity ride the result (``filter_path`` /
     ``filter_selectivity``).
     """
     store = backend.store
@@ -1048,7 +1105,7 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
 
     # --- predicate lane (core/filters.py) -------------------------------
     filter_path, filter_sel = "none", 1.0
-    alive_j = None
+    hmask = fmask_j = None
     if filter is not None:
         from repro.core.filters import (compile_filter, device_pass_mask,
                                         estimate_selectivity, host_pass)
@@ -1066,19 +1123,16 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
                                          alive, sp, pq, rerank_depth,
                                          h2d, cache_vec, f_lam, filter_sel)
         filter_path = "graph"
-        # composite alive: the predicate folds into the executor's
-        # existing -1/alive invalid-lane masking everywhere (entry pool,
-        # per-round valid, kernels' id -1 -> +inf), so filtered-out
-        # candidates never enter the pool. The host copy is a consistent
-        # cut of the bitset; the device twin below is ANDed from the
-        # epoch-synced attribute mirror for the fused in-cache rounds.
-        alive = alive & hmask                         # np copy, not a view
+        # nodes that fail the predicate stay traversable (the walk needs
+        # them to cross the graph) but never enter the result pool: host
+        # rounds admit by ``hmask``, fused rounds by its device twin,
+        # evaluated on the epoch-synced attribute mirror
         if pq is not None and topo is not None:
-            alive_j = jnp.asarray(backend.alive) & device_pass_mask(attrs,
-                                                                    cf)
+            fmask_j = device_pass_mask(attrs, cf)
     if entry_ids is None:
+        walk = L if hmask is None else filtered_walk_pool(L, k, filter_sel)
         rng = np.random.default_rng(seed)
-        entry_ids = rng.integers(0, n, (B, L))
+        entry_ids = rng.integers(0, n, (B, walk))
     entry_ids = np.asarray(entry_ids, np.int64)
 
     use_pq = pq is not None
@@ -1103,12 +1157,14 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
             predict_frontier
 
     entry_alive = alive[entry_ids]
+    entry_keep = None if hmask is None else \
+        jnp.asarray(entry_alive & hmask[entry_ids])
     if use_pq:
         # entry pool scored from device-resident codes: no vector fetch
         # at all (the lane's LUTs are built inside the same dispatch)
-        pool_ids, pool_d, visited, curr_j, lut = _pq_entry_dispatch(
+        pool_ids, pool_d, visited, curr_j, lut, res = _pq_entry_dispatch(
             jnp.asarray(entry_ids, jnp.int32), jnp.asarray(entry_alive),
-            codes_j, pq.codebook.centroids, qj, beam, id_bound)
+            codes_j, pq.codebook.centroids, qj, beam, id_bound, entry_keep)
         dispatches = 1
         if spec is not None:
             # no host vectors in the code lane: the entry prediction
@@ -1122,10 +1178,10 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
         else:
             uev, _ = _resolve_unique_vectors(ue, h2d, cache_vec, store,
                                              f_lam)
-        ev = uev[inv_e].reshape(B, L, D)
-        pool_ids, pool_d, visited, curr_j = _tiered_entry_dispatch(
+        ev = uev[inv_e].reshape(entry_ids.shape + (D,))
+        pool_ids, pool_d, visited, curr_j, res = _tiered_entry_dispatch(
             jnp.asarray(entry_ids, jnp.int32), jnp.asarray(ev),
-            jnp.asarray(entry_alive), qj, beam, id_bound)
+            jnp.asarray(entry_alive), qj, beam, id_bound, entry_keep)
         dispatches = 1
         if spec is not None:
             # stage round 1 while the entry dispatch is in flight: the
@@ -1144,12 +1200,12 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
         # fused multi-round executor: the shell owns the round loop and
         # issues ONE lax.while_loop dispatch per contiguous in-cache run
         (pool_ids, pool_d, acc_ids, it, extra, topo_hits,
-         topo_misses) = _fused_topo_shell(
+         topo_misses, res) = _fused_topo_shell(
             store, topo, spec, alive, f_lam, pq, codes_j, codes_epoch,
             lut, pool_ids, pool_d, visited, curr_j, beam, rounds,
             id_bound, fused_rounds,
             stage_width=(width if spec is not None else 0),
-            alive_j=alive_j)
+            res=res, hmask=hmask, fmask_j=fmask_j)
         dispatches += extra
     else:
         for _ in range(rounds):
@@ -1172,6 +1228,8 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
             nb = nb.reshape(B, C)
 
             valid = (nb >= 0) & alive[np.clip(nb, 0, None)]
+            keep = None if hmask is None else \
+                jnp.asarray(valid & hmask[np.clip(nb, 0, None)])
             if use_pq:
                 ep = store.write_epoch
                 if ep != codes_epoch:   # concurrent insert: fold fresh codes
@@ -1179,9 +1237,10 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
                     codes_j = pq.synced_codes()
                 # code-lane round: candidates scored from device-resident
                 # codes — nothing but the id matrix crosses to the device
-                pool_ids, pool_d, visited, curr_j = _pq_round_dispatch(
+                pool_ids, pool_d, visited, curr_j, res = _pq_round_dispatch(
                     pool_ids, pool_d, visited, jnp.asarray(nb),
-                    jnp.asarray(valid), codes_j, lut, beam, id_bound)
+                    jnp.asarray(valid), codes_j, lut, beam, id_bound, res,
+                    keep)
                 dispatches += 1
                 acc_ids[:, it] = np.where(valid, nb, -1)
                 if spec is not None:
@@ -1200,9 +1259,10 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
             # launch the round's single device dispatch (async); pool state
             # stays device-resident, only `curr` crosses back. The speculative
             # stage below overlaps with the in-flight dispatch.
-            pool_ids, pool_d, visited, curr_j = _tiered_round_dispatch(
+            pool_ids, pool_d, visited, curr_j, res = _tiered_round_dispatch(
                 pool_ids, pool_d, visited, jnp.asarray(nb), jnp.asarray(uvec),
-                jnp.asarray(inv), jnp.asarray(valid), qj, beam, id_bound)
+                jnp.asarray(inv), jnp.asarray(valid), qj, beam, id_bound,
+                res, keep)
             dispatches += 1
             acc_ids[:, it] = np.where(valid, nb, -1)
             acc_hit[:, it] = uhit[inv] & valid
@@ -1221,6 +1281,8 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
             curr = np.asarray(curr_j)             # the round's only sync point
             it += 1
 
+    if res is not None:      # filtered: results come from the result pool
+        pool_ids, pool_d = res
     if use_pq:
         # device-hit flags for the placement pass: in the code lane an
         # access "hits" when its id sits in the exact-vector device cache
@@ -1262,7 +1324,8 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
 def brute_force_topk(graph: GraphState, queries, k):
     """Exact ground truth over alive vectors (recall oracle)."""
     d = (jnp.sum(queries ** 2, 1, keepdims=True)
-         - 2.0 * queries @ graph.vectors.T
+         - 2.0 * jnp.matmul(queries, graph.vectors.T,
+                            precision=jax.lax.Precision.HIGHEST)
          + jnp.sum(graph.vectors ** 2, 1)[None, :])
     d = jnp.where(graph.alive[None, :], d, INF)
     nd, idx = jax.lax.top_k(-d, k)

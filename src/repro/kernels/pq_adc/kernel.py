@@ -1,24 +1,33 @@
-"""Fused code-gather + ADC-scan Pallas TPU kernel.
+"""ADC-scan Pallas TPU kernel over gathered PQ code rows.
 
-The inner loop of the PQ code lane (quant.py): for each query, fetch the
-C candidate code rows named by the frontier executor's id matrix and
-accumulate the asymmetric distance from the query's precomputed lookup
-table — ``d[c] = Σ_s lut[s, codes[c, s]]``. The structure mirrors
-``l2_gather``: neighbor ids scalar-prefetched (SMEM), per-id row DMAs
-HBM→VMEM, then one dense contraction — except the gathered rows are m
-uint8 codes instead of D fp32 lanes (D·4/m less DMA traffic, the whole
-point of the lane), and the "distance" is a LUT gather, realized as a
-one-hot [C, m·K] × [m·K] contraction so it lands on the MXU instead of a
-serialized scalar gather loop.
+The inner loop of the PQ code lane (quant.py): for each query, score the
+C candidate code rows named by the frontier executor's id matrix from
+the query's precomputed lookup table — ``d[c] = Σ_s lut[s, codes[c,
+s]]``.
+
+The code rows are gathered by XLA (``codes[ids]``, m bytes per id)
+before the kernel. A per-id DMA inside the kernel, as ``l2_gather`` does
+for fp32 rows, is not expressible here: Mosaic DMAs only whole tiles of
+an 8-bit table (32 rows) or whole 128-lane rows of a 32-bit one, and a
+code row is m = 48 bytes.
+
+The LUT gather runs on the VPU as a select: for each subspace s the
+candidates' codes are compared against a lane iota over the K centroids
+and ``lut[s]`` is accumulated where they match, into a [TILE, K] buffer
+whose row sums are the distances. One ones-row contraction at
+``Precision.HIGHEST`` then sums each row and lands it lane-major as a
+(1, TILE) output slice; every addend is an exact LUT entry, so the
+distance is an fp32 sum of the same terms the reference adds.
 
 Ids may carry invalid lanes (-1: padded beam slots, pruned edges):
-clamped for the DMA, forced to +inf in-kernel — the code table is never
-indexed at -1, same contract as l2_gather.
+clamped for the gather, forced to +inf in-kernel — the code table is
+never indexed at -1, same contract as l2_gather.
 
-Grid: one step per query. The code table stays in ANY/HBM; only the C
-gathered rows touch VMEM (C·m bytes — for C=512, m=16 that is 8 KiB vs
-the exact lane's C·D·4). uint8 rows tile at (32, 128); interpret mode
-(this CPU container) is shape-agnostic. Validated against ref.py.
+Grid: (query, candidate tile of 128). Only one tile's 128 code rows and
+the [128, K] fp32 accumulator (128 KiB at K=256) touch VMEM, whatever C
+is. The LUT block index does not change along the tile axis, so it is
+fetched once per query. Validated in interpret mode against ref.py;
+compiled for v5e by tests/test_chip_compile.py.
 """
 from __future__ import annotations
 
@@ -27,81 +36,49 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+_TILE = 128     # candidates per grid step: one 128-lane output slice
 
 
-_TILE_C = 128   # candidate rows per one-hot contraction tile: bounds the
-#                 [TILE_C, m·K] fp32 intermediate at 2 MiB for m=16, K=256
-#                 (the scale preset's C = beam·R = 1024 would otherwise
-#                 materialize a 16 MiB tensor — the whole VMEM budget)
-
-
-def _kernel(ids_ref, lut_ref, idv_ref, codes_ref, out_ref, rows_ref, sem):
-    C = out_ref.shape[1]
-    b = pl.program_id(0)
-
-    def fetch(c, _):
-        idx = jnp.maximum(ids_ref[b, c], 0)    # clamp invalid lanes
-        cp = pltpu.make_async_copy(codes_ref.at[pl.ds(idx, 1), :],
-                                   rows_ref.at[pl.ds(c, 1), :], sem)
-        cp.start()
-        cp.wait()
-        return 0
-
-    jax.lax.fori_loop(0, C, fetch, 0)
-    lut = lut_ref[0]                                      # [m, K]
-    K = lut.shape[1]
-    lut_flat = lut.reshape(-1)
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, 1, K), 2)
-    tc = min(C, _TILE_C)    # wrapper pads C to a multiple of _TILE_C
-
-    def tile(t, _):
-        # LUT gather as a one-hot contraction: flat index s·K + code
-        # selects lut[s, code]; the [tc, m·K] × [m·K] product runs on
-        # the MXU, one bounded tile of candidates at a time
-        cod = rows_ref[pl.ds(t * tc, tc), :].astype(jnp.int32)  # [tc, m]
-        onehot = (cod[:, :, None] == iota_k).astype(jnp.float32)
-        d = jnp.dot(onehot.reshape(tc, -1), lut_flat,
-                    preferred_element_type=jnp.float32)   # [tc]
-        out_ref[0, pl.ds(t * tc, tc)] = jnp.where(
-            idv_ref[0, pl.ds(t * tc, tc)] >= 0, d, jnp.inf)
-        return 0
-
-    jax.lax.fori_loop(0, C // tc, tile, 0)
+def _kernel(lut_ref, rows_ref, idv_ref, out_ref):
+    m, n_cent = lut_ref.shape
+    cod = rows_ref[...].astype(jnp.int32)                 # [TILE, m]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (1, n_cent), 1)
+    acc = jnp.zeros((cod.shape[0], n_cent), jnp.float32)
+    for s in range(m):
+        acc = acc + jnp.where(cod[:, s:s + 1] == iota, lut_ref[s:s + 1, :],
+                              0.0)
+    ones = jnp.ones((1, n_cent), jnp.float32)
+    d = jax.lax.dot_general(ones, acc, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)  # [1, TILE]
+    out_ref[...] = jnp.where(idv_ref[...] >= 0, d, jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pq_adc(codes, lut, ids, *, interpret=True):
+def pq_adc(codes, lut, ids, *, interpret=False):
     """codes [N, m] uint8; lut [B, m, K] f32; ids [B, C] int32 (-1 =
     invalid lane) -> ADC distances [B, C] fp32, +inf on invalid lanes."""
     B, C0 = ids.shape
-    N, m = codes.shape
-    K = lut.shape[2]
-    # pad the lane axis to a whole number of contraction tiles (-1 lanes
-    # come back +inf and are sliced off below)
-    C = -(-C0 // min(C0, _TILE_C)) * min(C0, _TILE_C)
-    if C != C0:
-        ids = jnp.concatenate(
-            [ids, jnp.full((B, C - C0), -1, ids.dtype)], axis=1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, m, K), lambda b, ids: (b, 0, 0)),    # ADC LUT
-            pl.BlockSpec((1, C), lambda b, ids: (b, 0)),          # valid mask
-            pl.BlockSpec(memory_space=pltpu.ANY),                 # codes HBM
-        ],
-        out_specs=pl.BlockSpec((1, C), lambda b, ids: (b, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((C, m), jnp.uint8),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
+    m = codes.shape[1]
+    n_cent = lut.shape[2]
+    # pad the lane axis to whole tiles (-1 lanes come back +inf and are
+    # sliced off below)
+    C = -(-C0 // _TILE) * _TILE
     ids = ids.astype(jnp.int32)
+    if C != C0:
+        ids = jnp.concatenate([ids, jnp.full((B, C - C0), -1, jnp.int32)], 1)
+    rows = codes.astype(jnp.uint8)[jnp.clip(ids, 0)]       # [B, C, m]
     out = pl.pallas_call(
         _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),
+        grid=(B, C // _TILE),
+        in_specs=[
+            pl.BlockSpec((None, m, n_cent), lambda b, t: (b, 0, 0)),
+            pl.BlockSpec((None, _TILE, m), lambda b, t: (b, t, 0)),
+            pl.BlockSpec((None, 1, _TILE), lambda b, t: (b, 0, t)),
+        ],
+        out_specs=pl.BlockSpec((None, 1, _TILE), lambda b, t: (b, 0, t)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, C), jnp.float32),
         interpret=interpret,
-    )(ids, lut.astype(jnp.float32), ids, codes.astype(jnp.uint8))
-    return out[:, :C0]
+    )(lut.astype(jnp.float32), rows, ids[:, None, :])
+    return out[:, 0, :C0]

@@ -10,12 +10,19 @@ round-trip: a non-resident id in the frontier surfaces as an all--1 row
 *plus* a cleared residency bit, and the loop exits to the host for the
 delta fetch.
 
-TPU-native shape (same house idiom as ``l2_gather``): frontier ids are
-scalar-prefetched (SMEM), each lane chains two DMAs — one element of the
-h2s directory HBM→SMEM to find the slot, then the slot's row HBM→VMEM —
-and the masking runs vectorized over the gathered [W, R] block. Directory
-and row table stay in ANY/HBM; only W rows (W·R·4 bytes) touch VMEM.
-Validated in interpret mode against ref.py (CPU container).
+TPU-native shape (same house idiom as ``l2_gather``): the wrapper
+resolves each lane's slot through the directory (an XLA gather of one
+int32 per lane — Mosaic cannot DMA a single element of the tiled [N]
+directory) and scalar-prefetches the [B, W] slot matrix into SMEM, -1 on
+idle or non-resident lanes. Each lane with a slot DMAs its row
+HBM→VMEM straight into the output block; a lane without one stores a -1
+row. The decision is a scalar one per lane, so no mask vector is built.
+Mosaic DMAs only whole 128-lane rows of a 32-bit table, so the wrapper
+pads R up to a multiple of 128 (R=32 -> 128; XLA's HBM layout pads the
+rows to 128 lanes anyway) and slices the pad lanes off the result. The
+row table stays in ANY/HBM; only W rows touch VMEM. Validated in
+interpret mode against ref.py; compiled for v5e by
+tests/test_chip_compile.py.
 """
 from __future__ import annotations
 
@@ -26,60 +33,56 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_LANES = 128
 
-def _kernel(ids_ref, idv_ref, h2s_ref, table_ref, out_ref,
-            rows_ref, slots_ref, slot1_ref, sem):
-    W = out_ref.shape[1]
+
+def _kernel(slots_ref, table_ref, out_ref, sem):
+    W, R = out_ref.shape
     b = pl.program_id(0)
 
     def fetch(w, _):
-        idx = jnp.maximum(ids_ref[b, w], 0)    # clamp idle lanes
-        cp = pltpu.make_async_copy(h2s_ref.at[pl.ds(idx, 1)],
-                                   slot1_ref.at[pl.ds(0, 1)], sem)
-        cp.start()
-        cp.wait()
-        slot = slot1_ref[0]
-        slots_ref[0, w] = slot
-        cp2 = pltpu.make_async_copy(
-            table_ref.at[pl.ds(jnp.maximum(slot, 0), 1), :],
-            rows_ref.at[pl.ds(w, 1), :], sem)
-        cp2.start()
-        cp2.wait()
+        slot = slots_ref[b, w]
+
+        @pl.when(slot >= 0)
+        def _():
+            cp = pltpu.make_async_copy(table_ref.at[pl.ds(slot, 1), :],
+                                       out_ref.at[pl.ds(w, 1), :], sem)
+            cp.start()
+            cp.wait()
+
+        @pl.when(slot < 0)
+        def _():
+            out_ref[pl.ds(w, 1), :] = jnp.full((1, R), -1, jnp.int32)
+
         return 0
 
     jax.lax.fori_loop(0, W, fetch, 0)
-    rows = rows_ref[...]                       # [W, R] VMEM
-    ok = (idv_ref[0] >= 0) & (slots_ref[0] >= 0)
-    out_ref[0] = jnp.where(ok[:, None], rows, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def row_gather(table, h2s, ids, *, interpret=True):
+def row_gather(table, h2s, ids, *, interpret=False):
     """table [S, R] int32 cached rows; h2s [N] int32 id->slot (-1 =
     non-resident); ids [B, W] int32 (-1 = idle lane) -> [B, W, R] int32
     adjacency rows, -1-filled on non-resident/idle lanes."""
     B, W = ids.shape
-    S, R = table.shape
+    S, R0 = table.shape
+    R = -(-R0 // _LANES) * _LANES
+    table = table.astype(jnp.int32)
+    if R != R0:
+        table = jnp.pad(table, ((0, 0), (0, R - R0)))
+    ids = ids.astype(jnp.int32)
+    slots = jnp.where(ids >= 0, h2s.astype(jnp.int32)[jnp.clip(ids, 0)], -1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, W), lambda b, ids: (b, 0)),      # valid mask
-            pl.BlockSpec(memory_space=pltpu.ANY),             # h2s HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),             # table HBM
-        ],
-        out_specs=pl.BlockSpec((1, W, R), lambda b, ids: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((W, R), jnp.int32),
-            pltpu.VMEM((1, W), jnp.int32),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA,
-        ],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],      # table HBM
+        out_specs=pl.BlockSpec((None, W, R), lambda b, slots: (b, 0, 0)),
+        scratch_shapes=[pltpu.SemaphoreType.DMA],
     )
-    ids = ids.astype(jnp.int32)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, W, R), jnp.int32),
         interpret=interpret,
-    )(ids, ids, h2s.astype(jnp.int32), table.astype(jnp.int32))
+    )(slots, table)
+    return out[:, :, :R0]

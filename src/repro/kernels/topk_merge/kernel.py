@@ -70,7 +70,7 @@ def _kernel(pool_d_ref, pool_i_ref, pool_v_ref, new_d_ref, new_i_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def topk_merge(pool_d, pool_i, pool_v, new_d, new_i, *, interpret=True):
+def topk_merge(pool_d, pool_i, pool_v, new_d, new_i, *, interpret=False):
     """Merge pools. pool_* [B, L]; new_* [B, R] -> best-L (d, i, visited)."""
     B, L = pool_d.shape
     R = new_d.shape[1]

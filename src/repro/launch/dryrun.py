@@ -17,7 +17,6 @@ import traceback         # noqa: E402
 
 import jax               # noqa: E402
 
-from repro import compat                                       # noqa: E402
 from repro.configs.base import ARCH_IDS, SHAPES, shape_cells   # noqa: E402
 from repro.launch.mesh import make_production_mesh             # noqa: E402
 from repro.models.sharding import axis_size, rules_override    # noqa: E402
@@ -74,9 +73,9 @@ def collective_bytes(hlo: str) -> dict:
 def _lower_compile(fn, args, in_sh, out_sh, donate):
     kw = {}
     if in_sh is not None:
-        kw["in_shardings"] = compat.resolve_shardings(in_sh)
+        kw["in_shardings"] = in_sh
     if out_sh is not None:
-        kw["out_shardings"] = compat.resolve_shardings(out_sh)
+        kw["out_shardings"] = out_sh
     if donate:
         kw["donate_argnums"] = donate
     jitted = jax.jit(fn, **kw)
@@ -119,7 +118,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, force=False) -> dict:
     record = {"arch": arch, "shape": shape, "mesh": mesh_name,
               "n_chips": int(mesh.devices.size), "ok": False}
     try:
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             rules = Steps.run_plan_rules(arch, shape)
             record["rules"] = {k: list(v) for k, v in rules.items()}
             with rules_override(**rules):

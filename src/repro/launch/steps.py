@@ -79,8 +79,7 @@ def plan_rules(arch: str, shape_name: str) -> dict:
         rules["batch"] = ()          # e.g. long_500k batch=1
         return rules
     cfg = load_config(arch)
-    from repro import compat
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     data_model = sizes.get("data", 1) * sizes.get("model", 1)
     layer_slab_gb = count_params(cfg) / max(cfg.n_layers, 1) * 2 / 1e9

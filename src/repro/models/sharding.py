@@ -21,7 +21,6 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 DEFAULT_RULES: dict[str, tuple[str, ...]] = {
     "batch": ("pod", "data"),
@@ -54,8 +53,8 @@ def rules_override(**kw):
 
 
 def mesh_axis_names() -> tuple[str, ...]:
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return ()
     return tuple(mesh.axis_names)
 
@@ -100,8 +99,8 @@ def weight_gather(cfg, w, axes):
 
 
 def axis_size(logical: str) -> int:
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return 1
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     n = 1

@@ -49,9 +49,8 @@ def make_crosspod_grad_sync(mesh, spec_tree, axis_name="pod"):
     """Wrap per-pod gradients with an EF-int8 pmean over the pod axis."""
     def sync(grads, errs):
         def one(g, e, spec):
-            from repro import compat
             inner = partial(ef_int8_psum, axis_name=axis_name)
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 inner, mesh=mesh,
                 in_specs=(spec, spec), out_specs=(spec, spec))
             return fn(g, e)

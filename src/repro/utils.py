@@ -3,6 +3,8 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import os
+import pathlib
 import time
 from typing import Any, Callable, Iterable
 
@@ -10,6 +12,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+
+
+CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compile cache where ``JAX_COMPILATION_CACHE_DIR``
+    says (JAX reads that variable itself; nothing is set in code then), or
+    else in the checkout's git-ignored ``.jax_cache/``. The path is part
+    of the cache key, so it is fixed: never a temporary name, process id
+    or time. Returns the directory in use. Call before the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def tree_size(tree) -> int:
@@ -77,8 +96,7 @@ def spec(*names) -> P:
 
 
 def current_mesh_axis_sizes() -> dict[str, int]:
-    from repro import compat
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return {}
     return dict(zip(mesh.axis_names, mesh.axis_sizes))

@@ -248,3 +248,62 @@ def test_vectorized_clock_matches_sequential_semantics():
     victim = int(np.argmin(evict_key))
     assert victim not in protected
     assert occ_score[victim] == evict_key.min()
+
+
+def test_compile_cache_location(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache lands in the checkout's one fixed, git-ignored dir."""
+    from repro.utils import CHECKOUT, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+        assert use_compile_cache() == "/elsewhere/jax"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = use_compile_cache()
+        assert path == str(CHECKOUT / ".jax_cache") == use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert ".jax_cache/" in (CHECKOUT / ".gitignore").read_text()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def _distance_fns():
+    from repro.core import quant
+    from repro.core.build import pairwise_l2
+    from repro.core.search import _batch_sqdist
+    from repro.core.types import GraphState
+    from repro.kernels.l2_gather.kernel import l2_gather
+    from repro.kernels.pq_adc.kernel import pq_adc
+    x, q = jnp.ones((2, 3, 4)), jnp.ones((2, 4))
+    ids = jnp.zeros((2, 8), jnp.int32)
+    g = GraphState(vectors=jnp.ones((5, 4)), nbrs=jnp.zeros((5, 2), jnp.int32),
+                   alive=jnp.ones((5,), bool), e_in=jnp.zeros((5,), jnp.int32),
+                   version=jnp.zeros((5,), jnp.int32), n=jnp.int32(5))
+    return {
+        "batch_sqdist": (_batch_sqdist, (x, q)),
+        "pairwise_l2": (pairwise_l2, (q, q)),
+        "brute_force_topk": (lambda g, q: brute_force_topk(g, q, 2), (g, q)),
+        "pq_centroids": (quant._sqdist_to_centroids,
+                         (jnp.ones((6, 2, 2)), jnp.ones((2, 8, 2)))),
+        "pq_train": (lambda v: quant._train(v, KEY, 2, 4, 1),
+                     (jnp.ones((16, 4)),)),
+        "l2_gather_kernel": (lambda t, i, q: l2_gather(t, i, q, interpret=True),
+                             (jnp.ones((5, 4)), ids, q)),
+        "pq_adc_kernel": (lambda c, lut, i: pq_adc(c, lut, i, interpret=True),
+                          (jnp.ones((5, 2), jnp.uint8), jnp.ones((2, 2, 4)),
+                           ids)),
+    }
+
+
+@pytest.mark.parametrize("name", list(_distance_fns()))
+def test_distance_contractions_are_fp32(name):
+    """Every distance contraction asks for HIGHEST precision: the TPU's
+    default rounds matmul operands to bf16, which neither the exact
+    re-rank nor the recall reference may do. (On the CPU the precision
+    changes nothing, so the lowered program is what is checked.)"""
+    fn, args = _distance_fns()[name]
+    text = jax.jit(fn).lower(*args).as_text()
+    dots = [l for l in text.splitlines() if "dot_general" in l]
+    assert dots, name
+    assert all("precision = [HIGHEST, HIGHEST]" in l for l in dots), dots

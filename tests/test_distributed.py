@@ -17,7 +17,6 @@ def run_sub(body: str) -> dict:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         sys.path.insert(0, {src!r})
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         out = {{}}
     """).format(src=SRC) + textwrap.dedent(body) + \
         "\nprint('RESULT::' + json.dumps(out))\n"
@@ -56,7 +55,7 @@ def test_distributed_search_matches_single_device():
           "f_recent": np.zeros((N,), np.float32),
         }
         Q = rng.normal(size=(32, D)).astype(np.float32)
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jidx = {k: jnp.asarray(v) for k, v in idx.items()}
             ids, dists = jax.jit(step)(jidx, jnp.asarray(Q),
                                        jax.random.PRNGKey(0))
@@ -86,12 +85,11 @@ def test_data_parallel_train_matches_single_device():
         loss_single = float(Mdl.loss_fn(cfg, params, batch))
 
         mesh = make_test_mesh((4, 2), ("data", "model"))
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             p_spec = Mdl.param_specs(cfg)
             b_spec = {"tokens": P("data", None), "labels": P("data", None)}
             f = jax.jit(lambda p, b: Mdl.loss_fn(cfg, p, b),
-                        in_shardings=compat.resolve_shardings(
-                            (p_spec, b_spec)))
+                        in_shardings=(p_spec, b_spec))
             loss_sharded = float(f(params, batch))
         out["single"] = loss_single
         out["sharded"] = loss_sharded
@@ -112,11 +110,10 @@ def test_seq_sharded_decode_attention_no_kv_allgather():
         B, T, H, Dh = 2, 1024, 4, 16
         q = jax.ShapeDtypeStruct((B, 1, H, Dh), jnp.bfloat16)
         kv = jax.ShapeDtypeStruct((B, T, H, Dh), jnp.bfloat16)
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             low = jax.jit(lambda q, k, v: decode_attention(q, k, v, T),
-                          in_shardings=compat.resolve_shardings(
-                              (P(), P(None, "model", None, None),
-                               P(None, "model", None, None)))
+                          in_shardings=(P(), P(None, "model", None, None),
+                                        P(None, "model", None, None))
                           ).lower(q, kv, kv)
             txt = low.compile().as_text()
         kv_bytes = B*T*H*Dh*2
@@ -148,7 +145,7 @@ def test_elastic_remesh_preserves_values():
         x = jnp.arange(64.0).reshape(8, 8)
         tree = {"w": x, "b": jnp.ones((8,))}
         spec = {"w": P("data", "model"), "b": P("data")}
-        with compat.use_mesh(big):
+        with jax.set_mesh(big):
             placed = jax.tree.map(
                 lambda a, s: jax.device_put(
                     a, jax.NamedSharding(big, s)), tree, spec)
@@ -171,11 +168,11 @@ def test_crosspod_ef_int8_grad_sync():
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         # per-pod gradients differ; EF-int8 pmean over "pod"
         gp = jnp.stack([g, g * 3.0])     # pod-major view
-        fn = compat.shard_map(partial(ef_int8_psum, axis_name="pod"),
+        fn = jax.shard_map(partial(ef_int8_psum, axis_name="pod"),
                            mesh=mesh,
                            in_specs=(P("pod", "data"), P("pod", "data")),
                            out_specs=(P("pod", "data"), P("pod", "data")))
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             synced, err = fn(gp.reshape(16, 64), jnp.zeros((16, 64)))
         true_mean = np.asarray((g + 3*g) / 2.0)
         got = np.asarray(synced)[:8]
