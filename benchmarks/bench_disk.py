@@ -425,8 +425,9 @@ def _streaming_tiered(vecs, sp, results, seed, rounds=6, insert_chunk=128,
                 "rounds_per_query": st["search_rounds_per_batch"],
                 # single source: the engine's per-result dispatch counter
                 # threaded through TieredSearchResult (acceptance metric
-                # of the fused multi-round executor)
-                "dispatches_per_query": st["dispatches_per_query"],
+                # of the fused multi-round executor); counted per executor
+                # call, the key kept for the recorded results' history
+                "dispatches_per_query": st["search_dispatches_per_batch"],
                 "topo_hit_rate": st["topo_hit_rate"],
                 "qps_samples": qps_samples,
                 "spec_hit_rate": st["spec_hit_rate"],

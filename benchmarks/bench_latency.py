@@ -2,9 +2,12 @@
 (open-loop arrivals via the multi-stream runner). Search requests flow
 through the engine's cross-query coalescing scheduler, so higher offered
 rates should show deeper merged micro-batches (``coalesce_batch_mean``)
-rather than proportionally higher dispatch counts."""
+rather than proportionally higher dispatch counts. Inserts run on a
+stream of their own, each timed here."""
 from __future__ import annotations
 
+import queue
+import threading
 import time
 
 import numpy as np
@@ -27,6 +30,17 @@ def main(n=4000, dim=32, rates=(200, 1000, 4000), duration=3.0):
         runner = MultiStreamRunner(idx.engine, n_search_streams=2,
                                    max_batch=64, batch_timeout=0.002)
         runner.start()
+        ins: list = []
+        todo: queue.Queue = queue.Queue()
+
+        def inserter():
+            while (vecs := todo.get()) is not None:
+                t0 = time.perf_counter()
+                idx.insert(vecs)
+                ins.append(time.perf_counter() - t0)
+
+        th = threading.Thread(target=inserter, daemon=True)
+        th.start()
         t_end = time.perf_counter() + duration
         interval = 8.0 / rate                    # 8 queries per request
         nsub = 0
@@ -34,13 +48,14 @@ def main(n=4000, dim=32, rates=(200, 1000, 4000), duration=3.0):
             runner.submit_search(
                 rng.normal(size=(8, dim)).astype(np.float32), tag=nsub)
             if nsub % 10 == 0:
-                runner.submit_insert(
-                    rng.normal(size=(8, dim)).astype(np.float32))
+                todo.put(rng.normal(size=(8, dim)).astype(np.float32))
             nsub += 1
             time.sleep(interval)
+        todo.put(None)
+        th.join()
         runner.drain_and_stop()
         lats = sorted(r[2] for r in runner.results)
-        ins = sorted(idx.engine.latencies["insert"])
+        ins.sort()
         est = idx.engine.stats()
         s = {
             "p50_ms": percentile(lats, 50) * 1e3,

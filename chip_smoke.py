@@ -241,7 +241,7 @@ def serve(engine, ref, queries, mask, meter, name, filter=None,
             dist_gap_over_fp32_bound=worst, first_batch_s=lat[0],
             steady_batch_s=float(np.median(lat[1:])) if len(lat) > 1
             else lat[0],
-            dispatches_per_query=st["dispatches_per_query"],
+            search_dispatches_per_batch=st["search_dispatches_per_batch"],
             topo_hit_rate=st["topo_hit_rate"])
         if filter is not None:
             ph.out.update(path=st["filter_last_path"],

@@ -24,6 +24,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.types import CacheState, GraphState, IndexState, SearchParams, Stats
 
@@ -308,6 +309,7 @@ class TopoCache:
         self.installs = 0
         self.evictions = 0
         self.flushes = 0             # epoch-fence wholesale refreshes
+        self.h2d_bytes = 0           # bytes ``synced`` put on the device
         self._cursor = 0             # slots allotted once, like TieredStore
         self._dirty = True
         self._rows_j = None
@@ -328,22 +330,26 @@ class TopoCache:
         t = self.hits + self.misses
         return self.hits / t if t else 0.0
 
-    def validate(self, store) -> None:
+    def validate(self, store, tag=None) -> None:
         """Epoch fence: when the store's write epoch moved, re-read every
-        resident row wholesale (one bulk peek) and republish."""
-        ep = store.write_epoch
-        with self._lock:
-            if self.epoch is None:
+        resident row wholesale (one bulk peek) and republish. One
+        ``svf.topo_validate`` span, tagged with ``tag``."""
+        tag = tag or {}
+        with TraceAnnotation("svf.topo_validate", **tag):
+            ep = store.write_epoch
+            with self._lock:
+                if self.epoch is None:
+                    self.epoch = ep
+                    return
+                if ep == self.epoch:
+                    return
+                occ = self.slot_hid >= 0
+                if occ.any():
+                    self.rows[occ] = store.peek_rows(self.slot_hid[occ],
+                                                     tag=tag)
+                    self._dirty = True
                 self.epoch = ep
-                return
-            if ep == self.epoch:
-                return
-            occ = self.slot_hid >= 0
-            if occ.any():
-                self.rows[occ] = store.peek_rows(self.slot_hid[occ])
-                self._dirty = True
-            self.epoch = ep
-            self.flushes += 1
+                self.flushes += 1
 
     def install(self, ids, rows, f_lam=None, protect=None) -> bool:
         """Install rows for unique non-resident ``ids``; returns False
@@ -401,13 +407,16 @@ class TopoCache:
             rows[ok] = self.rows[s[ok]]
             return rows, ok
 
-    def synced(self):
+    def synced(self, tag=None):
         """Publish (rows, h2s) device mirrors; both republished together
-        so a dispatch can never pair an old directory with new rows."""
+        so a dispatch can never pair an old directory with new rows. A
+        re-put is one ``svf.topo_sync`` span, tagged with ``tag``."""
         with self._lock:
             if self._dirty or self._rows_j is None:
-                self._rows_j = jnp.asarray(self.rows)
-                self._h2s_j = jnp.asarray(self.h2s)
+                with TraceAnnotation("svf.topo_sync", **(tag or {})):
+                    self._rows_j = jnp.asarray(self.rows)
+                    self._h2s_j = jnp.asarray(self.h2s)
+                self.h2d_bytes += self._rows_j.nbytes + self._h2s_j.nbytes
                 self._dirty = False
             return self._rows_j, self._h2s_j
 

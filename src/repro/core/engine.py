@@ -20,6 +20,8 @@ scheduling (§5.2), bounded-version policy (§5.3).
 """
 from __future__ import annotations
 
+import contextlib
+import itertools
 import queue
 import threading
 import time
@@ -29,6 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import cache as Cache
 from repro.core import mvcc, slo, update
@@ -233,7 +236,11 @@ class CoalescingScheduler:
     eating the budget), clamped to [min_window, max_window]. Under
     pressure the tier degrades search quality (``slo.degrade_params``
     applied by the search_fn via ``degrade=level``) before any request
-    is shed."""
+    is shed.
+
+    ``search_fn(queries, batch=<dispatch sequence number>, **knobs)``
+    runs inside the dispatch's ``svf.dispatch`` span and tags its own
+    spans with ``batch``."""
 
     def __init__(self, search_fn, *, max_batch=256, max_window=2e-3,
                  min_window=5e-5, policy: Optional[slo.SLOPolicy] = None):
@@ -286,46 +293,53 @@ class CoalescingScheduler:
 
     def _run(self):
         while not self._stop.is_set():
+            seq = self.dispatches + 1     # this dispatch's sequence number
             batch = self.tier.collect(self.max_batch, self.window,
-                                      self._stop)
+                                      self._stop, tag={"batch": seq})
             if not batch:
                 continue
             rows = sum(len(f.queries) for f in batch)
             level = self.tier.level
-            ok = True
-            t0 = time.perf_counter()
-            try:
-                kw = {"degrade": level} if level > 0 else {}
-                if batch[0].filter is not None:
-                    # the tier guarantees a filter-homogeneous batch
-                    kw["filter"] = batch[0].filter
-                ids, dists = self._search(
-                    np.concatenate([f.queries for f in batch], axis=0),
-                    **kw)
-                off = 0
-                now = time.perf_counter()
-                for f in batch:
-                    b = len(f.queries)
-                    f.ids, f.dists = ids[off:off + b], dists[off:off + b]
-                    f.latency = now - f.submitted
-                    off += b
-            except Exception as e:
-                ok = False
-                for f in batch:
-                    f.error = e
-            finally:
-                dt = time.perf_counter() - t0
-                self.requests += len(batch)
-                self.queries += rows
-                self.dispatches += 1
-                if level > 0:
-                    self.degraded_dispatches += 1
-                if len(batch) > 1:
-                    self.coalesced += 1
-                self.tier.complete(batch, rows, dt, ok=ok)
-                for f in batch:
-                    f._event.set()
-                self._adapt_window(len(batch))
+            with TraceAnnotation("svf.dispatch", batch=seq, rows=rows,
+                                 requests=len(batch), level=level):
+                self._dispatch(batch, rows, level, seq)
+
+    def _dispatch(self, batch, rows, level, seq):
+        """One executor call over ``batch``, its demux and accounting."""
+        ok = True
+        t0 = time.perf_counter()
+        try:
+            kw = {"degrade": level} if level > 0 else {}
+            if batch[0].filter is not None:
+                # the tier guarantees a filter-homogeneous batch
+                kw["filter"] = batch[0].filter
+            ids, dists = self._search(
+                np.concatenate([f.queries for f in batch], axis=0),
+                batch=seq, **kw)
+            off = 0
+            now = time.perf_counter()
+            for f in batch:
+                b = len(f.queries)
+                f.ids, f.dists = ids[off:off + b], dists[off:off + b]
+                f.latency = now - f.submitted
+                off += b
+        except Exception as e:
+            ok = False
+            for f in batch:
+                f.error = e
+        finally:
+            dt = time.perf_counter() - t0
+            self.requests += len(batch)
+            self.queries += rows
+            self.dispatches += 1
+            if level > 0:
+                self.degraded_dispatches += 1
+            if len(batch) > 1:
+                self.coalesced += 1
+            self.tier.complete(batch, rows, dt, ok=ok)
+            for f in batch:
+                f._event.set()
+            self._adapt_window(len(batch))
 
     def _adapt_window(self, merged: int):
         """p99-targeted window control. Shrink on an uncoalesced dispatch
@@ -404,6 +418,8 @@ class SVFusionEngine:
         self._state_lock = threading.RLock()   # publish/subscribe
         self._update_lock = threading.Lock()   # serializes the update stream
         self._cache_lock = threading.Lock()
+        self._cache_lock_wait_s = 0.0          # seconds spent taking it
+        self._update_seq = itertools.count(1)  # insert/delete call numbers
         self._backend = None                   # TieredBackend in 3-tier mode
         self._placement = None                 # HostPlacement in 3-tier mode
         self._rng = np.random.default_rng(cfg.seed)
@@ -413,6 +429,7 @@ class SVFusionEngine:
         self._recovery = None              # wal.recover report when reopened
         self._durable_epoch = None         # last published manifest epoch
         self._degraded = None              # read-only reason once WAL fails
+        self._wal_retired = (0, 0)         # (fsyncs, bytes) of rotated logs
         self._batches_since_snapshot = 0
         if init_vectors is not None:
             init_vectors = np.asarray(init_vectors, np.float32)
@@ -472,8 +489,6 @@ class SVFusionEngine:
                 tenant_rate_limits=cfg.slo_tenant_rate_limits))
             if cfg.coalesce else None)
         self._bg_threads: list = []
-        self.latencies: dict[str, list] = {"search": [], "insert": [],
-                                           "delete": []}
 
     def _init_tiered(self, init_vectors, cfg: EngineConfig):
         from repro.core.build import build_tiered_backend
@@ -613,8 +628,17 @@ class SVFusionEngine:
             self._backend.wal = self._wal
 
     # ------------------------------------------------------------------
-    def _next_key(self):
+    @contextlib.contextmanager
+    def _wait_and_hold_cache(self):
+        """Wait for ``_cache_lock`` and hold it for the block; the seconds
+        spent waiting are counted in ``stats()["cache_lock_wait_s"]``."""
+        t0 = time.perf_counter()
         with self._cache_lock:
+            self._cache_lock_wait_s += time.perf_counter() - t0
+            yield
+
+    def _next_key(self):
+        with self._wait_and_hold_cache():
             self._key, sub = jax.random.split(self._key)
         return sub
 
@@ -691,17 +715,19 @@ class SVFusionEngine:
                                   tuple(self.cfg.slo_degrade_order))
 
     def _search_exec(self, queries, update_cache=True, degrade=0,
-                     filter=None):
+                     filter=None, batch=None):
         """One executor invocation (the coalescer's dispatch target).
         ``degrade`` > 0 dispatches at reduced search quality (graceful
-        degradation under overload — see ``core.slo``)."""
+        degradation under overload — see ``core.slo``). ``batch`` is the
+        coalescer's dispatch sequence number, carried by the executor's
+        spans."""
         if self._backend is not None:
             return self._search_tiered(queries, update_cache,
-                                       degrade=degrade, filter=filter)
+                                       degrade=degrade, filter=filter,
+                                       batch=batch)
         if filter is not None:
             raise ValueError("filtered search requires the three-tier "
                              "mode with cfg.attributes set")
-        t0 = time.perf_counter()
         sp, _ = self._degraded_knobs(degrade)
         st = self._read_state()
         queries = jnp.asarray(queries, jnp.float32)
@@ -726,11 +752,10 @@ class SVFusionEngine:
                                        self.cfg.search,
                                        now=self._update_batches)
                 self._state = cur._replace(cache=new.cache, stats=new.stats)
-        self.latencies["search"].append(time.perf_counter() - t0)
         return ids, np.asarray(res.dists)
 
     def _search_tiered(self, queries, update_cache=True, degrade=0,
-                       filter=None):
+                       filter=None, batch=None):
         """Three-tier search: speculative pipeline + cascading lookup +
         post-batch host placement. Batches are padded to power-of-two
         buckets so the coalescer's variable micro-batch sizes compile
@@ -738,8 +763,8 @@ class SVFusionEngine:
         dispatches with the serving tier's reduced-quality knobs (beam /
         hop budget / re-rank depth per ``slo_degrade_order``)."""
         from repro.core.search import search_tiered
-        t0 = time.perf_counter()
-        with self._cache_lock:
+        tag = {} if batch is None else {"batch": batch}
+        with self._wait_and_hold_cache():
             seed = int(self._rng.integers(0, 2 ** 31 - 1))
         backend = self._backend
         sp, rerank_depth = self._degraded_knobs(degrade)
@@ -749,7 +774,8 @@ class SVFusionEngine:
         if Bp != B:
             queries = np.concatenate(
                 [queries, np.zeros((Bp - B, queries.shape[1]), np.float32)])
-        f_lam = self._placement.scores(backend.e_in)   # one O(N) pass/batch
+        with TraceAnnotation("svf.f_lambda", **tag):
+            f_lam = self._placement.scores(backend.e_in)   # O(N), once/batch
         res = search_tiered(
             self._backend, self._placement, queries, seed, sp,
             f_lam=f_lam,
@@ -762,12 +788,14 @@ class SVFusionEngine:
             topo=(backend.topo if self.cfg.pq_enabled else None),
             fused_rounds=self.cfg.fused_rounds,
             filter=filter,
-            filter_fallback_selectivity=self.cfg.filter_fallback_selectivity)
+            filter_fallback_selectivity=self.cfg.filter_fallback_selectivity,
+            tag=tag)
         if Bp != B:   # drop pad lanes from results AND placement logs
             res = res._replace(ids=res.ids[:B], dists=res.dists[:B],
                                acc_ids=res.acc_ids[:B],
                                acc_hit=res.acc_hit[:B])
-        with self._cache_lock:    # concurrent search streams share these
+        # concurrent search streams share these
+        with self._wait_and_hold_cache():
             self._search_rounds += res.iters
             self._search_dispatches += res.dispatches
             self._search_batches += 1
@@ -782,16 +810,16 @@ class SVFusionEngine:
                 self._filter_last_selectivity = res.filter_selectivity
                 self._filter_last_path = res.filter_path
         if update_cache:
-            with self._cache_lock:
+            with (TraceAnnotation("svf.wavp_place", **tag),
+                  self._wait_and_hold_cache()):
                 Cache.apply_wavp_host(
                     self._placement, res.acc_ids, res.acc_hit,
                     self.cfg.search, alive=backend.alive,
                     e_in=backend.e_in,
                     fetch_vectors=lambda i: backend.store.fetch(
-                        i, f_lam, count=False)[0],
+                        i, f_lam, count=False, tag=tag)[0],
                     now=self._update_batches,
                     cascade_promote=self.cfg.wavp_cascade_promote)
-        self.latencies["search"].append(time.perf_counter() - t0)
         return res.ids, res.dists
 
     def insert(self, vectors, chunk=512, attributes=None):
@@ -800,10 +828,15 @@ class SVFusionEngine:
         exact KNN stitch among the first chunk). ``attributes`` (dict of
         column -> per-row values, see ``filters.AttributeSchema.coerce``)
         tags the batch for filtered search — requires ``cfg.attributes``
-        and the three-tier mode."""
-        t0 = time.perf_counter()
-        self._check_writable()
+        and the three-tier mode. The call is one ``svf.insert`` span; its
+        ``step`` tags the spans of the update path below it."""
         vectors = np.asarray(vectors, np.float32)
+        step = next(self._update_seq)
+        with TraceAnnotation("svf.insert", step=step, rows=len(vectors)):
+            return self._insert(vectors, chunk, attributes, {"step": step})
+
+    def _insert(self, vectors, chunk, attributes, tag):
+        self._check_writable()
         attr_cols = None
         if attributes is not None:
             if self._backend is None or self._backend.attrs is None:
@@ -816,7 +849,7 @@ class SVFusionEngine:
             for s in range(0, len(vectors), chunk):
                 part_np = vectors[s:s + chunk]
                 if self._backend is not None:
-                    with self._cache_lock:
+                    with self._wait_and_hold_cache():
                         seed = int(self._rng.integers(0, 2 ** 31 - 1))
                     part_attrs = None
                     if attr_cols is not None:
@@ -825,7 +858,8 @@ class SVFusionEngine:
                     try:
                         ids, rev = update.insert_tiered(
                             self._backend, self._placement, part_np,
-                            self.cfg.search, seed, attributes=part_attrs)
+                            self.cfg.search, seed, attributes=part_attrs,
+                            tag=tag)
                     except walmod.WALWriteError as e:
                         self._degrade(str(e))
                     if self._snapshot_n is not None and len(rev.v):
@@ -842,9 +876,11 @@ class SVFusionEngine:
                         # re-read). Uses the same F_λ eviction order as
                         # demand installs when the cache is partial.
                         arr = np.asarray(ids, np.int64)
-                        topo.install(
-                            arr, self._backend.store.peek_rows(arr),
-                            self._placement.scores(self._backend.e_in))
+                        with TraceAnnotation("svf.topo_install", **tag):
+                            topo.install(
+                                arr,
+                                self._backend.store.peek_rows(arr, tag=tag),
+                                self._placement.scores(self._backend.e_in))
                     self._update_batches += 1
                     self._batches_since_repair += 1
                     self._batches_since_snapshot += 1
@@ -864,9 +900,8 @@ class SVFusionEngine:
                 self._update_batches += 1
                 self._batches_since_repair += 1
                 out.append(np.asarray(ids))
-        self._maybe_maintain()
-        self._maybe_checkpoint()
-        self.latencies["insert"].append(time.perf_counter() - t0)
+        self._maybe_maintain(tag)
+        self._maybe_checkpoint(tag)
         return np.concatenate(out)
 
     def _bootstrap_insert(self, st, part):
@@ -894,7 +929,14 @@ class SVFusionEngine:
         return st._replace(graph=g), ids
 
     def delete(self, ids):
-        t0 = time.perf_counter()
+        """Delete ``ids``: one ``svf.delete`` span, its ``step`` tagging
+        the spans of the update path below it."""
+        ids = np.asarray(ids)
+        step = next(self._update_seq)
+        with TraceAnnotation("svf.delete", step=step, rows=len(ids)):
+            self._delete(ids, {"step": step})
+
+    def _delete(self, ids, tag):
         self._check_writable()
         with self._update_lock:
             if self._backend is not None:
@@ -902,7 +944,7 @@ class SVFusionEngine:
                 # update.delete_tiered (out-of-range ids are ignored,
                 # matching delete_batch's clip semantics)
                 try:
-                    update.delete_tiered(self._backend, ids)
+                    update.delete_tiered(self._backend, ids, tag=tag)
                 except walmod.WALWriteError as e:
                     self._degrade(str(e))
             else:
@@ -912,9 +954,8 @@ class SVFusionEngine:
             self._update_batches += 1
             self._batches_since_repair += 1
             self._batches_since_snapshot += 1
-        self._maybe_maintain()
-        self._maybe_checkpoint()
-        self.latencies["delete"].append(time.perf_counter() - t0)
+        self._maybe_maintain(tag)
+        self._maybe_checkpoint(tag)
 
     # ------------------------------------------------------------------
     # durability (core/wal.py)
@@ -946,42 +987,48 @@ class SVFusionEngine:
                     group_commit=self.cfg.wal_group_commit)
             except (OSError, walmod.WALWriteError) as e:
                 self._degrade(f"snapshot publish failed: {e}")
+            fs, nb = self._wal_retired
+            self._wal_retired = (fs + self._wal.fsyncs,
+                                 nb + self._wal.bytes)
             self._wal = new_wal
             self._backend.wal = new_wal
             self._durable_epoch = int(manifest["epoch"])
             self._batches_since_snapshot = 0
         return self._durable_epoch
 
-    def _maybe_checkpoint(self):
+    def _maybe_checkpoint(self, tag):
         k = self.cfg.snapshot_every_epochs
         if (self._wal is None or self._degraded or k <= 0
                 or self._batches_since_snapshot < k):
             return
-        self.checkpoint()
+        with TraceAnnotation("svf.checkpoint", **tag):
+            self.checkpoint()
 
     # ------------------------------------------------------------------
-    def _maybe_maintain(self):
+    def _maybe_maintain(self, tag):
         """Deletion-triggered maintenance (paper §5.2). Repair fires once
         per ``repair_every`` update batches (counted since the last scan,
         not by a modulo that triggers on the very first batch); the
         deleted fraction is read from a state snapshot taken under the
         lock. Tiered mode has no localized-repair stage — the streaming
-        consolidation covers it."""
+        consolidation covers it. Work done is an ``svf.maintain`` span."""
         with self._update_lock:
             due = self._batches_since_repair >= self.cfg.repair_every
             if due:
                 self._batches_since_repair = 0
                 if self._backend is None:
-                    with self._state_lock:
-                        st = self._state
-                    st, nrep = update.repair_affected(
-                        st, max_repair=self.cfg.repair_budget,
-                        threshold=self.cfg.repair_threshold)
-                    # repair only touches the graph: publish that field
-                    # alone so cache/stats updates from searches that ran
-                    # during the scan are not rolled back
-                    with self._state_lock:
-                        self._state = self._state._replace(graph=st.graph)
+                    with TraceAnnotation("svf.maintain", **tag):
+                        with self._state_lock:
+                            st = self._state
+                        st, nrep = update.repair_affected(
+                            st, max_repair=self.cfg.repair_budget,
+                            threshold=self.cfg.repair_threshold)
+                        # repair only touches the graph: publish that
+                        # field alone so cache/stats updates from searches
+                        # that ran during the scan are not rolled back
+                        with self._state_lock:
+                            self._state = self._state._replace(
+                                graph=st.graph)
         if self._backend is not None:
             frac = self._backend.deleted_fraction()
         else:
@@ -989,7 +1036,8 @@ class SVFusionEngine:
                 graph = self._state.graph
             frac = float(update.deleted_fraction(graph))
         if frac >= self.cfg.consolidate_threshold:
-            self.consolidate_async()
+            with TraceAnnotation("svf.maintain", **tag):
+                self.consolidate_async()
 
     def consolidate_async(self, wait=False):
         """Background global consolidation on an MVCC snapshot (device
@@ -1087,7 +1135,7 @@ class SVFusionEngine:
         if self._backend is not None:
             # tiered mode: the jit-side cache/stats view is materialized
             # on demand from the host mirrors
-            with self._cache_lock:
+            with self._wait_and_hold_cache():
                 st = st._replace(cache=self._placement.to_cache_state(),
                                  stats=self._placement.to_stats())
             with self._state_lock:
@@ -1105,12 +1153,11 @@ class SVFusionEngine:
             d.update(self._backend.tier_counts())
             nb = max(self._search_batches, 1)
             d["search_rounds_per_batch"] = self._search_rounds / nb
-            d["search_dispatches_per_batch"] = self._search_dispatches / nb
             # single source for the fused-executor acceptance metric: the
             # per-result dispatch counts threaded through
             # TieredSearchResult (coalescing makes a "batch" one device
             # dispatch stream regardless of how many callers it serves)
-            d["dispatches_per_query"] = self._search_dispatches / nb
+            d["search_dispatches_per_batch"] = self._search_dispatches / nb
             d["topo_hits"] = self._topo_hits
             d["topo_misses"] = self._topo_misses
             d["topo_hit_rate"] = (self._topo_hits
@@ -1131,6 +1178,9 @@ class SVFusionEngine:
             if self._wal is not None:
                 d["wal_last_seq"] = self._wal.last_seq
                 d["wal_records"] = self._wal.appended
+                # running totals over every log segment, rotated or live
+                d["wal_fsyncs"] = self._wal_retired[0] + self._wal.fsyncs
+                d["wal_bytes"] = self._wal_retired[1] + self._wal.bytes
                 d["durable_epoch"] = self._durable_epoch
             # filter lane observability: counts, last routing decision and
             # the selectivity threshold the router compares against
@@ -1176,9 +1226,11 @@ class SVFusionEngine:
             d["n"] = int(st.graph.n)
             d["alive"] = int(st.graph.alive.sum())
         d["consolidations"] = self._consolidations
+        d["cache_lock_wait_s"] = self._cache_lock_wait_s
         if self._coalescer is not None:
             c = self._coalescer
             d["coalesce_requests"] = c.requests
+            d["queue_wait_s"] = c.tier.queue_wait_s
             d["coalesce_dispatches"] = c.dispatches
             d["coalesce_batch_mean"] = c.queries / max(c.dispatches, 1)
             d["coalesce_window_us"] = c.window * 1e6

@@ -251,15 +251,16 @@ def _device_pass(tags_j, nums_j, tag_masks, num_lo, num_hi):
     return ok
 
 
-def device_pass_mask(attrs, cf: CompiledFilter):
+def device_pass_mask(attrs, cf: CompiledFilter, put=jnp.asarray):
     """Per-id predicate mask evaluated ON DEVICE against the attribute
     store's epoch-synced mirror: bool [capacity] device array that, with
     ``alive``, admits candidates to the fused dispatch's result pool. One
     tiny jitted dispatch per search batch; the fused round loop then
-    just gathers from it."""
+    just gathers from it. ``put`` stages the compiled predicate on the
+    device (the executor passes its counting ``to_device``)."""
     tags_j, nums_j = attrs.synced()
-    return _device_pass(tags_j, nums_j, jnp.asarray(cf.tag_masks),
-                        jnp.asarray(cf.num_lo), jnp.asarray(cf.num_hi))
+    return _device_pass(tags_j, nums_j, put(cf.tag_masks), put(cf.num_lo),
+                        put(cf.num_hi))
 
 
 def estimate_selectivity(cf: CompiledFilter, attrs, alive, n: int,

@@ -212,6 +212,7 @@ class PQCodes:
         self._dirty: list = []
         self._lock = threading.Lock()
         self.encoded = 0          # rows encoded incrementally (stats)
+        self.h2d_bytes = 0        # bytes ``synced_codes`` put on the device
 
     @property
     def m(self) -> int:
@@ -239,7 +240,9 @@ class PQCodes:
         with self._lock:
             if self._dirty:
                 ids = np.unique(np.concatenate(self._dirty))
-                self._codes_j = self._codes_j.at[ids].set(self.codes[ids])
+                idx, rows = jnp.asarray(ids), jnp.asarray(self.codes[ids])
+                self.h2d_bytes += idx.nbytes + rows.nbytes
+                self._codes_j = self._codes_j.at[idx].set(rows)
                 self._dirty.clear()
             return self._codes_j
 

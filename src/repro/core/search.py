@@ -45,6 +45,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.quant import adc_lut
 from repro.core.types import CacheState, GraphState, IndexState, SearchParams
@@ -499,8 +500,9 @@ def _pq_fused_dispatch(pool_ids, pool_d, visited, curr, r, acc_ids,
 
 def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
                       codes_epoch, lut, pool_ids, pool_d, visited, curr_j,
-                      beam, rounds, id_bound, fused_rounds, stage_width=0,
-                      res=None, hmask=None, fmask_j=None):
+                      beam, rounds, id_bound, fused_rounds, put,
+                      stage_width=0, res=None, hmask=None, fmask_j=None,
+                      tag=None):
     """Host fallback shell around ``_pq_fused_dispatch``: the executor's
     round loop when a topology cache is attached. Steady state is ONE
     fused dispatch covering every remaining round (dispatches/query drops
@@ -524,6 +526,9 @@ def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
     candidates by the device pass mask ``fmask_j`` in fused rounds and by
     its host twin ``hmask`` in per-round fallback rounds.
 
+    ``put`` stages host arrays on the device (``TieredBackend.to_device``);
+    ``tag`` identifies the shell's spans.
+
     Returns (pool_ids, pool_d, acc [B, rounds, C] np.int32, rounds
     executed, dispatches issued, topo hits, topo misses, res)."""
     B = int(pool_ids.shape[0])
@@ -537,8 +542,9 @@ def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
     r = 0
     curr = np.asarray(curr_j)
     no_progress = 0
+    tag = tag or {}
     while r < rounds and (curr >= 0).any():
-        topo.validate(store)
+        topo.validate(store, tag)
         ep = store.write_epoch
         if ep != codes_epoch:       # concurrent insert: fold fresh codes
             codes_epoch = ep
@@ -553,22 +559,23 @@ def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
         if need.size:
             misses += int(need.size)
             topo.misses += int(need.size)
-            if spec is not None:
-                spec.validate()
-                rows_need = spec.rows_for(need)
-            else:
-                rows_need = store.fetch_rows(need, f_lam)
-            # the live frontier is protected: an install can never evict
-            # the rows the dispatch it feeds is about to gather
-            installed = topo.install(need, rows_need, f_lam, protect=ucur)
+            with TraceAnnotation("svf.topo_install", **tag):
+                if spec is not None:
+                    spec.validate()
+                    rows_need = spec.rows_for(need)
+                else:
+                    rows_need = store.fetch_rows(need, f_lam, tag=tag)
+                # the live frontier is protected: an install can never
+                # evict the rows the dispatch it feeds is about to gather
+                installed = topo.install(need, rows_need, f_lam,
+                                         protect=ucur)
         if installed and no_progress < 3:
-            rows_j, h2s_j = topo.synced()
+            rows_j, h2s_j = topo.synced(tag)
             out = _pq_fused_dispatch(
                 pool_ids, pool_d, visited, curr_j,
-                jnp.asarray(r, jnp.int32), acc_j, rows_j, h2s_j, codes_j,
-                lut, jnp.asarray(alive),
-                jnp.asarray(min(r + K, rounds), jnp.int32), beam, id_bound,
-                res, fmask_j)
+                put(r, jnp.int32), acc_j, rows_j, h2s_j, codes_j,
+                lut, put(alive), put(min(r + K, rounds), jnp.int32), beam,
+                id_bound, res, fmask_j)
             dispatches += 1
             if spec is not None:
                 # topology prefetch one cache-miss ahead, overlapping the
@@ -584,7 +591,8 @@ def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
                         nxt = nxt[np.argpartition(-f_lam[nxt], w - 1)[:w]]
                     spec.stage(nxt)
             pool_ids, pool_d, visited, curr_j, r_j, acc_j, res = out
-            curr = np.asarray(curr_j)         # the shell's only sync point
+            with TraceAnnotation("svf.device_sync", **tag):
+                curr = np.asarray(curr_j)     # the shell's only sync point
             new_r = int(r_j)
             # a dispatch that advanced no round means residency changed
             # under us (concurrent install/evict): bounded retries, then
@@ -600,19 +608,21 @@ def _fused_topo_shell(store, topo, spec, alive, f_lam, pq, codes_j,
             nb = nb.reshape(B, C)
             valid = (nb >= 0) & alive[np.clip(nb, 0, None)]
             keep = None if hmask is None else \
-                jnp.asarray(valid & hmask[np.clip(nb, 0, None)])
+                put(valid & hmask[np.clip(nb, 0, None)])
             pool_ids, pool_d, visited, curr_j, res = _pq_round_dispatch(
-                pool_ids, pool_d, visited, jnp.asarray(nb),
-                jnp.asarray(valid), codes_j, lut, beam, id_bound, res, keep)
+                pool_ids, pool_d, visited, put(nb), put(valid), codes_j,
+                lut, beam, id_bound, res, keep)
             dispatches += 1
             if acc_np is None:
                 acc_np = np.full((B, rounds, C), -1, np.int32)
             acc_np[:, r] = np.where(valid, nb, -1)
             fb_rounds.append(r)
-            curr = np.asarray(curr_j)
+            with TraceAnnotation("svf.device_sync", **tag):
+                curr = np.asarray(curr_j)
             r += 1
             no_progress = 0
-    acc = np.array(acc_j)   # copy: jax buffers are read-only views
+    with TraceAnnotation("svf.device_sync", **tag):
+        acc = np.array(acc_j)   # copy: jax buffers are read-only views
     if fb_rounds:   # overlay host-logged fallback rounds onto the device log
         acc[:, fb_rounds] = acc_np[:, fb_rounds]
     return pool_ids, pool_d, acc, r, dispatches, hits, misses, res
@@ -696,7 +706,7 @@ class TieredSearchResult(NamedTuple):
         return self.topo_hits / t if t else 0.0
 
 
-def _resolve_unique_vectors(ids, h2d, cache_vec, store, f_lam):
+def _resolve_unique_vectors(ids, h2d, cache_vec, store, f_lam, tag=None):
     """Vectors for a batch of *unique* non-negative ids through the
     cascade device cache (mirror) -> host window -> disk. Returns
     (vectors [U, D] fp32, device_hit [U])."""
@@ -707,7 +717,7 @@ def _resolve_unique_vectors(ids, h2d, cache_vec, store, f_lam):
         out[hit] = cache_vec[slot[hit]]
     miss = ~hit
     if miss.any():
-        out[miss] = store.fetch(ids[miss], f_lam)[0]
+        out[miss] = store.fetch(ids[miss], f_lam, tag=tag)[0]
     return out, hit
 
 
@@ -795,8 +805,9 @@ class _SpecPipeline:
     (MVCC consistency is the store's, unchanged)."""
 
     def __init__(self, backend, h2d, cache_vec, f_lam, *,
-                 prefetch_budget=0, probe=8, stage_vectors=True):
+                 prefetch_budget=0, probe=8, stage_vectors=True, tag=None):
         self.store = backend.store
+        self.tag = tag              # the search's span identifier
         self.h2d, self.cache_vec, self.f_lam = h2d, cache_vec, f_lam
         self.prefetch_budget = prefetch_budget
         self.probe = probe
@@ -828,7 +839,8 @@ class _SpecPipeline:
             self.misses += int(miss.sum())
         if miss.any():
             mids = uids[miss]
-            self.rows.add(mids, self.store.fetch_rows(mids, self.f_lam))
+            self.rows.add(mids, self.store.fetch_rows(mids, self.f_lam,
+                                                      tag=self.tag))
             loc = self.rows.loc[uids]
         return self.rows.buf[loc]
 
@@ -839,7 +851,7 @@ class _SpecPipeline:
         if miss.any():
             mids = uids[miss]
             v, h = _resolve_unique_vectors(mids, self.h2d, self.cache_vec,
-                                           self.store, self.f_lam)
+                                           self.store, self.f_lam, self.tag)
             self.vecs.add(mids, v, h)
             loc = self.vecs.loc[uids]
         return self.vecs.buf[loc], self.vecs.hit[loc]
@@ -867,17 +879,17 @@ class _SpecPipeline:
         if cand.size > self.probe:
             cand = cand[np.argpartition(-self.f_lam[cand],
                                         self.probe - 1)[:self.probe]]
-        hrows = self.store.peek_rows(cand)
+        hrows = self.store.peek_rows(cand, tag=self.tag)
         nxt = np.unique(hrows[hrows >= 0])
         nxt = nxt[self.store.loc[nxt] < 0]
         if nxt.size:
             b = self.prefetch_budget
             if nxt.size > b:
                 nxt = nxt[np.argpartition(-self.f_lam[nxt], b - 1)[:b]]
-            self.store.prefetch(nxt, self.f_lam)
+            self.store.prefetch(nxt, self.f_lam, tag=self.tag)
 
 
-def _predict_prefetch(store, nb, valid, f_lam, budget, probe=8):
+def _predict_prefetch(store, nb, valid, f_lam, budget, probe=8, tag=None):
     """Predicted next-frontier prefetch for the NON-speculative path
     (paper §4.4 multi-stream overlap): peek the hottest candidates'
     adjacency while the dispatch is in flight, enqueue their non-resident
@@ -887,13 +899,13 @@ def _predict_prefetch(store, nb, valid, f_lam, budget, probe=8):
         return
     if cand.size > probe:     # argpartition: this runs once per round
         cand = cand[np.argpartition(-f_lam[cand], probe - 1)[:probe]]
-    hrows = store.peek_rows(cand)
+    hrows = store.peek_rows(cand, tag=tag)
     nxt = np.unique(hrows[hrows >= 0])
     nxt = nxt[store.loc[nxt] < 0]
     if nxt.size:
         if nxt.size > budget:
             nxt = nxt[np.argpartition(-f_lam[nxt], budget - 1)[:budget]]
-        store.prefetch(nxt, f_lam)
+        store.prefetch(nxt, f_lam, tag=tag)
 
 
 def _ship_unique_vectors(ids, valid, resolve, pad_to=None):
@@ -955,7 +967,7 @@ def filtered_walk_pool(pool: int, k: int, selectivity: float) -> int:
 
 def _filtered_brute_force(backend, queries, qj, hmask, alive_snap, sp,
                           pq, rerank_depth, h2d, cache_vec, f_lam,
-                          filter_sel) -> TieredSearchResult:
+                          filter_sel, tag) -> TieredSearchResult:
     """Selectivity-adaptive fallback arm of ``search_tiered``: exact
     search restricted to the matched id set, no graph traversal. PQ
     mode: ONE ``pq_adc`` scan over the matched ids keeps the top
@@ -981,27 +993,30 @@ def _filtered_brute_force(backend, queries, qj, hmask, alive_snap, sp,
     cand[:matched.size] = matched
     cand_ids = np.broadcast_to(cand, (B, Mp))
     dispatches = 0
+    put = backend.to_device
     if pq is not None:
         codes_j = pq.synced_codes()
         depth = min(effective_rerank_depth(rerank_depth, k, sp.pool), Mp)
         top_j, _ = _pq_filtered_scan_dispatch(
-            codes_j, pq.codebook.centroids, qj,
-            jnp.asarray(cand_ids, jnp.int32), depth)
+            codes_j, pq.codebook.centroids, qj, put(cand_ids, jnp.int32),
+            depth)
         dispatches += 1
-        top_ids = np.asarray(top_j, np.int64)
+        with TraceAnnotation("svf.device_sync", **tag):
+            top_ids = np.asarray(top_j, np.int64)
     else:
         top_ids = cand_ids
     valid_r = top_ids >= 0
-    uvec, _, inv = _ship_unique_vectors(
-        top_ids, valid_r,
-        lambda u: _resolve_unique_vectors(u, h2d, cache_vec, backend.store,
-                                          f_lam))
+    with TraceAnnotation("svf.rerank_fetch", **tag):
+        uvec, _, inv = _ship_unique_vectors(
+            top_ids, valid_r,
+            lambda u: _resolve_unique_vectors(u, h2d, cache_vec,
+                                              backend.store, f_lam, tag))
     ids_k, d_k = _pq_rerank_dispatch(
-        jnp.asarray(top_ids, jnp.int32), jnp.asarray(uvec),
-        jnp.asarray(inv), jnp.asarray(valid_r), qj, k)
+        put(top_ids, jnp.int32), put(uvec), put(inv), put(valid_r), qj, k)
     dispatches += 1
-    ids_np = np.asarray(ids_k, np.int32)
-    d_np = np.asarray(d_k, np.float32)
+    with TraceAnnotation("svf.device_sync", **tag):
+        ids_np = np.asarray(ids_k, np.int32)
+        d_np = np.asarray(d_k, np.float32)
     if ids_np.shape[1] < k:      # fewer matches than k: pad the tail
         pad = k - ids_np.shape[1]
         ids_np = np.pad(ids_np, ((0, 0), (0, pad)), constant_values=-1)
@@ -1021,7 +1036,7 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
                   rerank_depth: int = 0, topo=None,
                   fused_rounds: int = 0, filter=None,
                   filter_fallback_selectivity: float = 0.0,
-                  filter_sample: int = 1024) -> TieredSearchResult:
+                  filter_sample: int = 1024, tag=None) -> TieredSearchResult:
     """Hop-batched frontier search over a disk-backed graph (paper
     Algorithm 1 in its GPU-CPU-disk form) — the tiered arm of the shared
     executor, run as a two-stage speculative pipeline. Per round: ONE
@@ -1082,6 +1097,12 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
     (``_filtered_brute_force``). The chosen path and the measured
     selectivity ride the result (``filter_path`` /
     ``filter_selectivity``).
+
+    Every host array the search puts on the device goes through
+    ``backend.to_device``, which counts its bytes. ``tag`` (``batch=`` of
+    the coalescer's dispatch, or ``step=`` of an insert) identifies the
+    search's spans: ``svf.entry``, ``svf.fused``, ``svf.device_sync`` at
+    each read-back, ``svf.rerank_fetch``.
     """
     store = backend.store
     alive = backend.alive
@@ -1101,7 +1122,9 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
     C = beam * R
     n = max(backend.n, 1)
     id_bound = int(backend.capacity)
-    qj = jnp.asarray(queries)
+    tag = tag or {}
+    put = backend.to_device
+    qj = put(queries)
 
     # --- predicate lane (core/filters.py) -------------------------------
     filter_path, filter_sel = "none", 1.0
@@ -1121,14 +1144,15 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
             # graph walk would starve: brute-force scan the matched set
             return _filtered_brute_force(backend, queries, qj, hmask,
                                          alive, sp, pq, rerank_depth,
-                                         h2d, cache_vec, f_lam, filter_sel)
+                                         h2d, cache_vec, f_lam, filter_sel,
+                                         tag)
         filter_path = "graph"
         # nodes that fail the predicate stay traversable (the walk needs
         # them to cross the graph) but never enter the result pool: host
         # rounds admit by ``hmask``, fused rounds by its device twin,
         # evaluated on the epoch-synced attribute mirror
         if pq is not None and topo is not None:
-            fmask_j = device_pass_mask(attrs, cf)
+            fmask_j = device_pass_mask(attrs, cf, put)
     if entry_ids is None:
         walk = L if hmask is None else filtered_walk_pool(L, k, filter_sel)
         rng = np.random.default_rng(seed)
@@ -1150,7 +1174,7 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
     if speculate:
         spec = _SpecPipeline(backend, h2d, cache_vec, f_lam,
                              prefetch_budget=prefetch_budget,
-                             stage_vectors=not use_pq)
+                             stage_vectors=not use_pq, tag=tag)
         spec.validate()
         width = spec_width if spec_width > 0 else beam
         predict = spec_predict if spec_predict is not None else \
@@ -1158,39 +1182,45 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
 
     entry_alive = alive[entry_ids]
     entry_keep = None if hmask is None else \
-        jnp.asarray(entry_alive & hmask[entry_ids])
-    if use_pq:
-        # entry pool scored from device-resident codes: no vector fetch
-        # at all (the lane's LUTs are built inside the same dispatch)
-        pool_ids, pool_d, visited, curr_j, lut, res = _pq_entry_dispatch(
-            jnp.asarray(entry_ids, jnp.int32), jnp.asarray(entry_alive),
-            codes_j, pq.codebook.centroids, qj, beam, id_bound, entry_keep)
-        dispatches = 1
-        if spec is not None:
-            # no host vectors in the code lane: the entry prediction
-            # falls back to the F_λ probe (rows-only staging)
-            spec.stage(predict(entry_ids, entry_alive, f_lam, width))
-    else:
-        # entry pool: one unique-id cascade + one entry dispatch
-        ue, inv_e = np.unique(entry_ids.reshape(-1), return_inverse=True)
-        if spec is not None:
-            uev, _ = spec.vectors_for(ue)
+        put(entry_alive & hmask[entry_ids])
+    with TraceAnnotation("svf.entry", **tag):
+        if use_pq:
+            # entry pool scored from device-resident codes: no vector
+            # fetch at all (the lane's LUTs are built inside the same
+            # dispatch)
+            pool_ids, pool_d, visited, curr_j, lut, res = \
+                _pq_entry_dispatch(put(entry_ids, jnp.int32),
+                                   put(entry_alive), codes_j,
+                                   pq.codebook.centroids, qj, beam,
+                                   id_bound, entry_keep)
+            dispatches = 1
+            if spec is not None:
+                # no host vectors in the code lane: the entry prediction
+                # falls back to the F_λ probe (rows-only staging)
+                spec.stage(predict(entry_ids, entry_alive, f_lam, width))
         else:
-            uev, _ = _resolve_unique_vectors(ue, h2d, cache_vec, store,
-                                             f_lam)
-        ev = uev[inv_e].reshape(entry_ids.shape + (D,))
-        pool_ids, pool_d, visited, curr_j, res = _tiered_entry_dispatch(
-            jnp.asarray(entry_ids, jnp.int32), jnp.asarray(ev),
-            jnp.asarray(entry_alive), qj, beam, id_bound, entry_keep)
-        dispatches = 1
-        if spec is not None:
-            # stage round 1 while the entry dispatch is in flight: the
-            # entry vectors are host-resident, so the first frontier is
-            # predicted from exact host distances
-            pred = predict(entry_ids, entry_alive, f_lam, width,
-                           d_host=_host_sqdist(ev, queries))
-            spec.stage(pred)
-    curr = np.asarray(curr_j)                 # [B, beam], -1 = idle lane
+            # entry pool: one unique-id cascade + one entry dispatch
+            ue, inv_e = np.unique(entry_ids.reshape(-1),
+                                  return_inverse=True)
+            if spec is not None:
+                uev, _ = spec.vectors_for(ue)
+            else:
+                uev, _ = _resolve_unique_vectors(ue, h2d, cache_vec, store,
+                                                 f_lam, tag)
+            ev = uev[inv_e].reshape(entry_ids.shape + (D,))
+            pool_ids, pool_d, visited, curr_j, res = _tiered_entry_dispatch(
+                put(entry_ids, jnp.int32), put(ev), put(entry_alive), qj,
+                beam, id_bound, entry_keep)
+            dispatches = 1
+            if spec is not None:
+                # stage round 1 while the entry dispatch is in flight: the
+                # entry vectors are host-resident, so the first frontier
+                # is predicted from exact host distances
+                pred = predict(entry_ids, entry_alive, f_lam, width,
+                               d_host=_host_sqdist(ev, queries))
+                spec.stage(pred)
+        with TraceAnnotation("svf.device_sync", **tag):
+            curr = np.asarray(curr_j)         # [B, beam], -1 = idle lane
 
     acc_ids = np.full((B, rounds, C), -1, np.int32)
     acc_hit = np.zeros((B, rounds, C), bool)
@@ -1199,13 +1229,14 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
     if use_pq and topo is not None:
         # fused multi-round executor: the shell owns the round loop and
         # issues ONE lax.while_loop dispatch per contiguous in-cache run
-        (pool_ids, pool_d, acc_ids, it, extra, topo_hits,
-         topo_misses, res) = _fused_topo_shell(
-            store, topo, spec, alive, f_lam, pq, codes_j, codes_epoch,
-            lut, pool_ids, pool_d, visited, curr_j, beam, rounds,
-            id_bound, fused_rounds,
-            stage_width=(width if spec is not None else 0),
-            res=res, hmask=hmask, fmask_j=fmask_j)
+        with TraceAnnotation("svf.fused", **tag):
+            (pool_ids, pool_d, acc_ids, it, extra, topo_hits,
+             topo_misses, res) = _fused_topo_shell(
+                store, topo, spec, alive, f_lam, pq, codes_j, codes_epoch,
+                lut, pool_ids, pool_d, visited, curr_j, beam, rounds,
+                id_bound, fused_rounds, put,
+                stage_width=(width if spec is not None else 0),
+                res=res, hmask=hmask, fmask_j=fmask_j, tag=tag)
         dispatches += extra
     else:
         for _ in range(rounds):
@@ -1220,7 +1251,7 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
                 spec.validate()
                 urows = spec.rows_for(ucur)
             else:
-                urows = store.fetch_rows(ucur, f_lam)
+                urows = store.fetch_rows(ucur, f_lam, tag=tag)
             nb = np.full((B, beam, R), -1, np.int32)
             # searchsorted over the (sorted) unique ids: O(|curr| log |ucur|),
             # no O(dataset) scratch on the per-round hot path
@@ -1229,7 +1260,7 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
 
             valid = (nb >= 0) & alive[np.clip(nb, 0, None)]
             keep = None if hmask is None else \
-                jnp.asarray(valid & hmask[np.clip(nb, 0, None)])
+                put(valid & hmask[np.clip(nb, 0, None)])
             if use_pq:
                 ep = store.write_epoch
                 if ep != codes_epoch:   # concurrent insert: fold fresh codes
@@ -1238,31 +1269,31 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
                 # code-lane round: candidates scored from device-resident
                 # codes — nothing but the id matrix crosses to the device
                 pool_ids, pool_d, visited, curr_j, res = _pq_round_dispatch(
-                    pool_ids, pool_d, visited, jnp.asarray(nb),
-                    jnp.asarray(valid), codes_j, lut, beam, id_bound, res,
-                    keep)
+                    pool_ids, pool_d, visited, put(nb), put(valid), codes_j,
+                    lut, beam, id_bound, res, keep)
                 dispatches += 1
                 acc_ids[:, it] = np.where(valid, nb, -1)
                 if spec is not None:
                     if it + 1 < rounds:
                         spec.stage(predict(nb, valid, f_lam, width))
                 elif prefetch_budget > 0:
-                    _predict_prefetch(store, nb, valid, f_lam, prefetch_budget)
-                curr = np.asarray(curr_j)         # the round's only sync point
+                    _predict_prefetch(store, nb, valid, f_lam,
+                                      prefetch_budget, tag=tag)
+                with TraceAnnotation("svf.device_sync", **tag):
+                    curr = np.asarray(curr_j)     # the round's only sync
                 it += 1
                 continue
             uvec, uhit, inv = _ship_unique_vectors(
                 nb, valid,
                 spec.vectors_for if spec is not None else
                 (lambda u: _resolve_unique_vectors(u, h2d, cache_vec, store,
-                                                   f_lam)))
+                                                   f_lam, tag)))
             # launch the round's single device dispatch (async); pool state
             # stays device-resident, only `curr` crosses back. The speculative
             # stage below overlaps with the in-flight dispatch.
             pool_ids, pool_d, visited, curr_j, res = _tiered_round_dispatch(
-                pool_ids, pool_d, visited, jnp.asarray(nb), jnp.asarray(uvec),
-                jnp.asarray(inv), jnp.asarray(valid), qj, beam, id_bound,
-                res, keep)
+                pool_ids, pool_d, visited, put(nb), put(uvec), put(inv),
+                put(valid), qj, beam, id_bound, res, keep)
             dispatches += 1
             acc_ids[:, it] = np.where(valid, nb, -1)
             acc_hit[:, it] = uhit[inv] & valid
@@ -1277,8 +1308,10 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
                         d_host = _host_sqdist(uvec[inv], queries)
                     spec.stage(predict(nb, valid, f_lam, width, d_host=d_host))
             elif prefetch_budget > 0:
-                _predict_prefetch(store, nb, valid, f_lam, prefetch_budget)
-            curr = np.asarray(curr_j)             # the round's only sync point
+                _predict_prefetch(store, nb, valid, f_lam, prefetch_budget,
+                                  tag=tag)
+            with TraceAnnotation("svf.device_sync", **tag):
+                curr = np.asarray(curr_j)         # the round's only sync
             it += 1
 
     if res is not None:      # filtered: results come from the result pool
@@ -1292,25 +1325,29 @@ def search_tiered(backend, cache_mirror, queries, seed, sp: SearchParams,
         acc_hit_flat = (h2d[np.clip(flat, 0, None)] >= 0) & (flat >= 0)
 
         # tier-cascade exact re-rank of the top ADC-ranked pool entries
-        pool_ids_np, pool_d_np = np.asarray(pool_ids), np.asarray(pool_d)
+        with TraceAnnotation("svf.device_sync", **tag):
+            pool_ids_np, pool_d_np = np.asarray(pool_ids), np.asarray(pool_d)
         top_ids = pool_ids_np[:, :depth]
         valid_r = (top_ids >= 0) & np.isfinite(pool_d_np[:, :depth])
-        uvec, _, inv = _ship_unique_vectors(
-            top_ids, valid_r,
-            lambda u: _resolve_unique_vectors(u, h2d, cache_vec, store,
-                                              f_lam),
-            pad_to=top_ids.size)
+        with TraceAnnotation("svf.rerank_fetch", **tag):
+            uvec, _, inv = _ship_unique_vectors(
+                top_ids, valid_r,
+                lambda u: _resolve_unique_vectors(u, h2d, cache_vec, store,
+                                                  f_lam, tag),
+                pad_to=top_ids.size)
         ids_k, d_k = _pq_rerank_dispatch(
-            jnp.asarray(top_ids, jnp.int32), jnp.asarray(uvec),
-            jnp.asarray(inv), jnp.asarray(valid_r), qj, k)
+            put(top_ids, jnp.int32), put(uvec), put(inv), put(valid_r), qj,
+            k)
         dispatches += 1
+        with TraceAnnotation("svf.device_sync", **tag):
+            ids_np, d_np = np.asarray(ids_k, np.int32), np.asarray(d_k)
         return TieredSearchResult(
-            np.asarray(ids_k, np.int32), np.asarray(d_k),
-            flat, acc_hit_flat, it, dispatches,
+            ids_np, d_np, flat, acc_hit_flat, it, dispatches,
             spec.hits if spec else 0, spec.misses if spec else 0,
             topo_hits, topo_misses, filter_path, filter_sel)
 
-    pool_ids, pool_d = np.asarray(pool_ids), np.asarray(pool_d)
+    with TraceAnnotation("svf.device_sync", **tag):
+        pool_ids, pool_d = np.asarray(pool_ids), np.asarray(pool_d)
     topk_ids = np.where(np.isfinite(pool_d[:, :k]), pool_ids[:, :k], -1)
     return TieredSearchResult(topk_ids.astype(np.int32), pool_d[:, :k],
                               acc_ids.reshape(B, -1),

@@ -53,6 +53,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.types import SearchParams
 
@@ -293,6 +294,8 @@ class ServingTier:
         self.deadline_miss_total = 0
         self.rate_limited_total = 0
         self.overshoot_avoided = 0   # admissions deferred at the batch cap
+        self.queue_wait_s = 0.0      # submit-to-admission seconds, summed
+        #                              over the requests popped into batches
         self.pressure = 0.0
 
     # -- client side ----------------------------------------------------
@@ -439,22 +442,28 @@ class ServingTier:
                 self._queued_requests -= 1
                 self._queued_rows -= r
                 ts.vtime += r / ts.weight
+                self.queue_wait_s += now - fut.submitted
                 return fut
             if not rescan:
                 return None
 
-    def collect(self, max_batch: int, window: float, stop) -> list:
+    def collect(self, max_batch: int, window: float, stop,
+                tag=None) -> list:
         """Assemble one dispatch batch: block (briefly) for the first
         request, then admit weighted-fair until the adaptive window
         closes, the batch fills, or the next head would overshoot the
         cap. Every pop happens under the lock, so a concurrent shutdown
         drain can never double-complete a future. Returns possibly-empty
-        list (caller re-checks its stop flag)."""
+        list (caller re-checks its stop flag). Each block is an
+        ``svf.coalesce_wait`` span carrying ``tag`` (the dispatcher's
+        ``batch``)."""
+        tag = tag or {}
         with self.cv:
             if self.closed or stop.is_set():
                 return []   # shutdown owns the queue now (drain)
             if self._queued_requests == 0:
-                self.cv.wait(timeout=0.05)
+                with TraceAnnotation("svf.coalesce_wait", **tag):
+                    self.cv.wait(timeout=0.05)
             if self.closed:
                 return []
             first = self._pop_next(0, max_batch)
@@ -476,7 +485,8 @@ class ServingTier:
                 left = deadline - time.perf_counter()
                 if left <= 0:
                     break
-                self.cv.wait(timeout=left)
+                with TraceAnnotation("svf.coalesce_wait", **tag):
+                    self.cv.wait(timeout=left)
             return batch
 
     def complete(self, batch: list, rows: int, dispatch_s: float,

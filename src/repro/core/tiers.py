@@ -11,22 +11,28 @@ with device compute (the multi-stream analogue, paper §4.4).
 
 Thread-safety: ``fetch``/``peek``/``write`` serialize on one reentrant
 lock; residency bookkeeping (``loc``/``slot_id``/host arrays) is only
-ever touched under it. The prefetcher performs its disk reads OUTSIDE
-the lock (so background IO genuinely overlaps foreground traffic) and
-re-validates residency + a store write-epoch before installing, dropping
-the batch if a write raced it. Its queue is bounded: under overload new
-predictions are dropped, not accumulated stale. Free slots are handed
-out by a monotone cursor (slots are never returned), so promotion is
-O(batch) instead of a per-miss ``np.where`` scan.
+ever touched under it. Each method is an ``svf.store.<method>`` span
+while it holds the lock, and the seconds threads spent waiting to take
+it are counted in ``lock_wait_s``. The prefetcher performs its disk
+reads OUTSIDE the lock (so background IO genuinely overlaps foreground
+traffic) and re-validates residency + a store write-epoch before
+installing, dropping the batch if a write raced it. Its queue is
+bounded: under overload new predictions are dropped, not accumulated
+stale. Free slots are handed out by a monotone cursor (slots are never
+returned), so promotion is O(batch) instead of a per-miss ``np.where``
+scan.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import queue
 import threading
+import time
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class DiskTier:
@@ -86,6 +92,7 @@ class TieredStore:
         self.demotions = 0
         self.prefetched = 0
         self.prefetch_dropped = 0
+        self.lock_wait_s = 0.0          # seconds spent taking ``_lock``
         self._lock = threading.RLock()
         self._free_cursor = 0           # slots are allotted once, never freed
         self._write_epoch = 0           # bumped by write(); guards installs
@@ -93,13 +100,24 @@ class TieredStore:
         self._stop = threading.Event()
         self._th: Optional[threading.Thread] = None
 
+    @contextlib.contextmanager
+    def _wait_and_hold(self, span: str, tag):
+        """Wait for ``_lock``, counting the wait in ``lock_wait_s``, and
+        hold it for the block as the span ``span`` tagged with ``tag``."""
+        t0 = time.perf_counter()
+        with self._lock:
+            self.lock_wait_s += time.perf_counter() - t0
+            with TraceAnnotation(span, **(tag or {})):
+                yield
+
     # -- residency ------------------------------------------------------
     def fetch(self, ids: np.ndarray, f_lambda: Optional[np.ndarray] = None,
-              *, count: bool = True):
+              *, count: bool = True, tag=None):
         """Read rows, promoting misses into the host window (demote lowest
-        F_λ residents when full). Returns (vectors, nbr_rows) copies."""
+        F_λ residents when full). Returns (vectors, nbr_rows) copies.
+        ``tag`` (here and below) is the caller's span identifier."""
         ids = np.asarray(ids)
-        with self._lock:
+        with self._wait_and_hold("svf.store.fetch", tag):
             out_v = np.empty((len(ids), self.disk.dim), np.float32)
             out_n = np.empty((len(ids), self.disk.degree), np.int32)
             slots = self.loc[ids]
@@ -117,11 +135,11 @@ class TieredStore:
                 self._promote(miss_ids, dv, dn, f_lambda)
             return out_v, out_n
 
-    def peek(self, ids: np.ndarray):
+    def peek(self, ids: np.ndarray, *, tag=None):
         """Read rows through the window overlay WITHOUT promotion or
         counter updates (maintenance scans must not thrash the window)."""
         ids = np.asarray(ids)
-        with self._lock:
+        with self._wait_and_hold("svf.store.peek", tag):
             out_v = np.empty((len(ids), self.disk.dim), np.float32)
             out_n = np.empty((len(ids), self.disk.degree), np.int32)
             slots = self.loc[ids]
@@ -136,13 +154,13 @@ class TieredStore:
 
     def fetch_rows(self, ids: np.ndarray,
                    f_lambda: Optional[np.ndarray] = None, *,
-                   count: bool = True):
+                   count: bool = True, tag=None):
         """Adjacency-only ``fetch`` (the speculative pipeline's delta-fetch
         API): window hits skip the vector copy entirely; misses read both
         halves from disk — the promotion install needs the vectors anyway
         — and promote exactly like ``fetch``. Returns nbr rows, a copy."""
         ids = np.asarray(ids)
-        with self._lock:
+        with self._wait_and_hold("svf.store.fetch_rows", tag):
             out_n = np.empty((len(ids), self.disk.degree), np.int32)
             slots = self.loc[ids]
             hit = slots >= 0
@@ -164,13 +182,13 @@ class TieredStore:
         it moves — a staged row must never outlive a concurrent write."""
         return self._write_epoch
 
-    def peek_rows(self, ids: np.ndarray):
+    def peek_rows(self, ids: np.ndarray, *, tag=None):
         """Adjacency-only ``peek``: rows through the window overlay
         without promotion, counters, or the vector copy. The MVCC
         snapshot and the prefetch predictor read topology at scale —
         copying D floats per id alongside would dominate their cost."""
         ids = np.asarray(ids)
-        with self._lock:
+        with self._wait_and_hold("svf.store.peek_rows", tag):
             out_n = np.empty((len(ids), self.disk.degree), np.int32)
             slots = self.loc[ids]
             hit = slots >= 0
@@ -179,12 +197,12 @@ class TieredStore:
                 out_n[~hit] = np.asarray(self.disk.nbr[ids[~hit]])
             return out_n
 
-    def write(self, ids, vectors=None, nbrs=None):
+    def write(self, ids, vectors=None, nbrs=None, *, tag=None):
         """Write-through update: disk always, host window where resident
         (keeps the overlay coherent without dirty tracking; demotion
         write-back then never loses updates)."""
         ids = np.asarray(ids)
-        with self._lock:
+        with self._wait_and_hold("svf.store.write", tag):
             self._write_epoch += 1
             self.disk.write(ids, vectors, nbrs)
             slots = self.loc[ids]
@@ -249,23 +267,25 @@ class TieredStore:
         def work():
             while not self._stop.is_set():
                 try:
-                    ids, f_lam = self._prefetch_q.get(timeout=0.05)
+                    ids, f_lam, tag = self._prefetch_q.get(timeout=0.05)
                 except queue.Empty:
                     continue
-                self._prefetch_one(np.unique(ids), f_lam)
+                self._prefetch_one(np.unique(ids), f_lam, tag)
         self._th = threading.Thread(target=work, daemon=True)
         self._th.start()
 
-    def _prefetch_one(self, ids, f_lam):
+    def _prefetch_one(self, ids, f_lam, tag=None):
         """One overlapped prefetch: residency probe under the lock, disk
-        read OUTSIDE it, install re-validated against the write epoch."""
-        with self._lock:
+        read OUTSIDE it, install re-validated against the write epoch. The
+        two locked sections are ``svf.store.prefetch`` spans carrying the
+        tag of the batch that asked for them."""
+        with self._wait_and_hold("svf.store.prefetch", tag):
             miss = ids[self.loc[ids] < 0]
             epoch = self._write_epoch
         if not miss.size:
             return
         dv, dn = self.disk.read(miss)          # overlaps foreground work
-        with self._lock:
+        with self._wait_and_hold("svf.store.prefetch", tag):
             if self._write_epoch != epoch:
                 self.prefetch_dropped += len(miss)
                 return                         # a write raced the read
@@ -274,12 +294,13 @@ class TieredStore:
                 self._promote(miss[still], dv[still], dn[still], f_lam)
                 self.prefetched += int(still.sum())
 
-    def prefetch(self, ids, f_lambda: Optional[np.ndarray] = None):
+    def prefetch(self, ids, f_lambda: Optional[np.ndarray] = None, *,
+                 tag=None):
         if self._stop.is_set():
             return                  # shutdown in flight: never enqueue work
             #                         the closing disk tier would receive
         try:
-            self._prefetch_q.put_nowait((np.asarray(ids), f_lambda))
+            self._prefetch_q.put_nowait((np.asarray(ids), f_lambda, tag))
         except queue.Full:
             self.prefetch_dropped += len(ids)  # overload: drop, don't lag
 
@@ -334,6 +355,7 @@ class AttributeStore:
         self._dirty: list = []
         self._lock = threading.Lock()
         self.written = 0    # ids written through (observability)
+        self.h2d_bytes = 0  # bytes ``synced`` put on the device
 
     def write(self, ids, tags, nums):
         """Write-through attribute install for freshly inserted ids:
@@ -357,10 +379,11 @@ class AttributeStore:
                 ids = np.concatenate(self._dirty)
                 self._dirty = []
                 idx = jnp.asarray(ids)
-                self._tags_j = self._tags_j.at[idx].set(
-                    jnp.asarray(self.tags[ids]))
-                self._nums_j = self._nums_j.at[idx].set(
-                    jnp.asarray(self.nums[ids]))
+                tags, nums = (jnp.asarray(self.tags[ids]),
+                              jnp.asarray(self.nums[ids]))
+                self.h2d_bytes += idx.nbytes + tags.nbytes + nums.nbytes
+                self._tags_j = self._tags_j.at[idx].set(tags)
+                self._nums_j = self._nums_j.at[idx].set(nums)
             return self._tags_j, self._nums_j
 
     def snapshot(self, n: int):
@@ -409,6 +432,17 @@ class TieredBackend:
         #                     predicate lane; host truth + epoch-synced
         #                     device mirror, written through by
         #                     update.insert_tiered, snapshot-persisted
+        self.h2d_bytes = 0  # bytes the executor staged host-to-device
+        #                     (``to_device``); the lanes count their own
+        self._h2d_lock = threading.Lock()
+
+    def to_device(self, x, dtype=None):
+        """``x`` as a device array, its bytes counted in ``h2d_bytes``."""
+        import jax.numpy as jnp
+        a = jnp.asarray(x, dtype)
+        with self._h2d_lock:
+            self.h2d_bytes += a.nbytes
+        return a
 
     def attach_topo(self, topo) -> None:
         """Attach the device-resident topology row cache
@@ -469,7 +503,14 @@ class TieredBackend:
                "host_miss_rate": s.miss_rate, "demotions": s.demotions,
                "prefetched": s.prefetched,
                "prefetch_dropped": s.prefetch_dropped,
-               "host_resident": s.resident}
+               "host_resident": s.resident,
+               "store_lock_wait_s": s.lock_wait_s,
+               # host-to-device bytes: the executor's staging plus the
+               # device mirrors of the lanes, each re-put when dirty
+               "h2d_bytes": self.h2d_bytes + sum(
+                   lane.h2d_bytes for lane in (self.pq, self.topo,
+                                               self.attrs)
+                   if lane is not None)}
         if self.pq is not None:
             out["pq_encoded_incremental"] = self.pq.encoded
         if self.attrs is not None:

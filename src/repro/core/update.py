@@ -14,6 +14,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.build import compute_e_in, rank_based_reorder
 from repro.core.search import _frontier_search, dedup_mask
@@ -232,7 +233,7 @@ def reverse_edge_rows_host(trow, tvec, nbr_vecs, inv, new_ids, d_edge):
 
 
 def insert_tiered(backend, cache_mirror, new_vecs, sp: SearchParams, seed,
-                  attributes=None):
+                  attributes=None, tag=None):
     """Batched insertion against the disk-backed capacity tier (paper §5.1
     over the three-tier hierarchy): candidate search cascades through the
     store, new rows are written through the host window, and reverse edges
@@ -254,8 +255,14 @@ def insert_tiered(backend, cache_mirror, new_vecs, sp: SearchParams, seed,
     any form ``filters.AttributeSchema.coerce`` accepts (dict of
     columns, (tags, nums) pair, or None for schema defaults); requires
     an attached ``backend.attrs`` store.
+
+    Spans, each tagged with ``tag`` (the engine's ``step=``):
+    ``svf.insert.search`` (candidate search), ``svf.insert.link``
+    (rank-based reorder and the reverse-edge triplets), the WAL's
+    ``svf.wal.append``, ``svf.insert.apply`` (the store writes).
     """
     from repro.core.search import search_tiered
+    tag = tag or {}
     store = backend.store
     new_vecs = np.asarray(new_vecs, np.float32)
     Bi = new_vecs.shape[0]
@@ -266,33 +273,36 @@ def insert_tiered(backend, cache_mirror, new_vecs, sp: SearchParams, seed,
     ids = (n0 + np.arange(Bi)).astype(np.int64)
     # one O(capacity) F_λ pass shared by the candidate search, the row
     # fetches and the reverse-edge pass below
-    f_lam = cache_mirror.scores(backend.e_in)
+    with TraceAnnotation("svf.f_lambda", **tag):
+        f_lam = cache_mirror.scores(backend.e_in)
 
     # phase 1: candidate search on the current graph
-    res = search_tiered(backend, cache_mirror, new_vecs, seed,
-                        sp._replace(k=sp.pool), f_lam=f_lam)
+    with TraceAnnotation("svf.insert.search", **tag):
+        res = search_tiered(backend, cache_mirror, new_vecs, seed,
+                            sp._replace(k=sp.pool), f_lam=f_lam, tag=tag)
     cand_ids, cand_d = res.ids.astype(np.int64), res.dists
 
-    # phase 2: rank-based reorder over the candidates' (fetched) rows
-    uc = np.unique(np.clip(cand_ids, 0, None))
-    _, urows = store.fetch(uc, f_lam)
-    cand_rows = urows[np.searchsorted(uc, np.clip(cand_ids, 0, None))]
-    cand_rows[cand_ids < 0] = -1
-    sel = rank_based_reorder_host(cand_ids, cand_d, cand_rows, R)
+    with TraceAnnotation("svf.insert.link", **tag):
+        # phase 2: rank-based reorder over the candidates' (fetched) rows
+        uc = np.unique(np.clip(cand_ids, 0, None))
+        _, urows = store.fetch(uc, f_lam, tag=tag)
+        cand_rows = urows[np.searchsorted(uc, np.clip(cand_ids, 0, None))]
+        cand_rows[cand_ids < 0] = -1
+        sel = rank_based_reorder_host(cand_ids, cand_d, cand_rows, R)
 
-    # reverse-edge triplets, pre-mutation (targets all pre-exist: their
-    # vectors are immutable and the distances are computable now)
-    flat_t = sel.reshape(-1).astype(np.int64)
-    flat_new = np.repeat(ids, R)
-    ok = flat_t >= 0
-    flat_t, flat_new = flat_t[ok], flat_new[ok]
-    d_edge = np.zeros((0,), np.float32)
-    if flat_t.size:
-        ut, inv = np.unique(flat_t, return_inverse=True)
-        tvec, _ = store.fetch(ut, f_lam)
-        d_edge = ((tvec[inv] - new_vecs[(flat_new - n0)]) ** 2).sum(-1)
-    rev = RevLog(flat_t.astype(np.int64), flat_new.astype(np.int64),
-                 np.asarray(d_edge, np.float32))
+        # reverse-edge triplets, pre-mutation (targets all pre-exist:
+        # their vectors are immutable and the distances are computable)
+        flat_t = sel.reshape(-1).astype(np.int64)
+        flat_new = np.repeat(ids, R)
+        ok = flat_t >= 0
+        flat_t, flat_new = flat_t[ok], flat_new[ok]
+        d_edge = np.zeros((0,), np.float32)
+        if flat_t.size:
+            ut, inv = np.unique(flat_t, return_inverse=True)
+            tvec, _ = store.fetch(ut, f_lam, tag=tag)
+            d_edge = ((tvec[inv] - new_vecs[(flat_new - n0)]) ** 2).sum(-1)
+        rev = RevLog(flat_t.astype(np.int64), flat_new.astype(np.int64),
+                     np.asarray(d_edge, np.float32))
 
     # attribute columns: coerce through the index schema so the WAL
     # record and the live apply share one validated column form
@@ -309,20 +319,21 @@ def insert_tiered(backend, cache_mirror, new_vecs, sp: SearchParams, seed,
                    "rev_v": rev.v, "rev_vn": rev.v_new, "rev_d": rev.d}
         if tags is not None:
             payload["tags"], payload["nums"] = tags, nums
-        backend.wal.append(walmod.REC_INSERT, payload)
-    apply_insert_tiered(backend, ids, new_vecs, sel, rev, f_lam=f_lam,
-                        tags=tags, nums=nums)
+        backend.wal.append(walmod.REC_INSERT, payload, tag=tag)
+    with TraceAnnotation("svf.insert.apply", **tag):
+        apply_insert_tiered(backend, ids, new_vecs, sel, rev, f_lam=f_lam,
+                            tags=tags, nums=nums, tag=tag)
     return ids, rev
 
 
 def apply_insert_tiered(backend, ids, new_vecs, sel, rev: RevLog,
-                        f_lam=None, tags=None, nums=None) -> None:
+                        f_lam=None, tags=None, nums=None, tag=None) -> None:
     """Mutation half of ``insert_tiered``, shared verbatim with WAL
     replay (``wal.recover``): establish the new vertices, encode their PQ
     codes against the frozen codebook, then apply the logged reverse
     edges onto freshly fetched target rows. Replaying this over the
     snapshot's state walks the store through the exact same write
-    sequence as the live run."""
+    sequence as the live run. ``tag`` identifies the store's spans."""
     from repro.core.wal import crash_point
     store = backend.store
     ids = np.asarray(ids, np.int64)
@@ -339,7 +350,7 @@ def apply_insert_tiered(backend, ids, new_vecs, sel, rev: RevLog,
     # the PQ code lane encodes incrementally against its frozen codebook
     # so the device-resident ADC scan covers the new ids from the next
     # search's epoch sync onward
-    store.write(ids, new_vecs, sel)
+    store.write(ids, new_vecs, sel, tag=tag)
     crash_point("mid_memmap_write")   # new rows written, reverse edges not
     if backend.pq is not None:
         backend.pq.encode_write(ids, new_vecs)
@@ -357,27 +368,27 @@ def apply_insert_tiered(backend, ids, new_vecs, sel, rev: RevLog,
         v_new = np.asarray(rev.v_new, np.int64)
         d_edge = np.asarray(rev.d, np.float32)
         ut, inv = np.unique(v, return_inverse=True)
-        tvec, trow = store.fetch(ut, f_lam)
-        rvec, _ = store.peek(np.clip(trow, 0, None).reshape(-1))
+        tvec, trow = store.fetch(ut, f_lam, tag=tag)
+        rvec, _ = store.peek(np.clip(trow, 0, None).reshape(-1), tag=tag)
         new_rows = reverse_edge_rows_host(
             trow, tvec, rvec.reshape(ut.size, R, -1), inv, v_new, d_edge)
         np.add.at(backend.e_in, trow[trow >= 0], -1)
         np.add.at(backend.e_in, new_rows[new_rows >= 0], 1)
-        store.write(ut, None, new_rows)
+        store.write(ut, None, new_rows, tag=tag)
         backend.version[ut] += 1
 
 
-def delete_tiered(backend, ids) -> np.ndarray:
+def delete_tiered(backend, ids, tag=None) -> np.ndarray:
     """Logical deletion on the tiered backend (stage 1, paper §5.2.1):
     bounds-filter, drop already-dead ids, WAL the surviving set, then
     flip the bitset. Returns the ids actually deleted. Caller serializes
-    (engine update stream)."""
+    (engine update stream); ``tag`` identifies the WAL's span."""
     ids_np = np.asarray(ids, np.int64)
     ids_np = ids_np[(ids_np >= 0) & (ids_np < backend.n)]
     ids_np = ids_np[backend.alive[ids_np]]
     if backend.wal is not None and ids_np.size:
         from repro.core import wal as walmod
-        backend.wal.append(walmod.REC_DELETE, {"ids": ids_np})
+        backend.wal.append(walmod.REC_DELETE, {"ids": ids_np}, tag=tag)
     apply_delete_tiered(backend, ids_np)
     return ids_np
 

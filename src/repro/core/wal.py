@@ -52,6 +52,7 @@ import zlib
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 MAGIC = b"SVWL"
 _HDR = struct.Struct("<4sBQII")       # magic, rtype, op_seq, payload_len, crc
@@ -149,6 +150,10 @@ class WriteAheadLog:
     group-commit throughput trade. A failed write/sync poisons the log
     (``failed``) so the engine can degrade to read-only; the store was
     not touched for the failed op (WAL-before-write).
+
+    Each append is an ``svf.wal.append`` span and each record fsync an
+    ``svf.wal.fsync`` span; ``fsyncs`` and ``bytes`` count them and the
+    frame bytes written.
     """
 
     def __init__(self, path: str, *, group_commit: int = 8,
@@ -157,6 +162,8 @@ class WriteAheadLog:
         self.group_commit = max(1, int(group_commit))
         self.appended = 0
         self.synced = 0
+        self.fsyncs = 0       # fsyncs: group commits and sync()
+        self.bytes = 0        # frame bytes written
         self.failed: Optional[str] = None
         self._next_seq = int(start_seq)
         self._pending = 0
@@ -178,19 +185,20 @@ class WriteAheadLog:
     def closed(self) -> bool:
         return self._f.closed
 
-    def append(self, rtype: int, payload: dict) -> int:
-        with self._lock:
+    def append(self, rtype: int, payload: dict, tag=None) -> int:
+        """Log one record; ``tag`` identifies its spans."""
+        tag = tag or {}
+        with TraceAnnotation("svf.wal.append", **tag), self._lock:
             if self.failed:
                 raise WALWriteError(self.failed)
             seq = self._next_seq
             try:
-                self._f.write(_frame(rtype, seq, payload))
+                frame = _frame(rtype, seq, payload)
+                self._f.write(frame)
+                self.bytes += len(frame)
                 self._pending += 1
                 if self._pending >= self.group_commit:
-                    self._f.flush()
-                    os.fsync(self._f.fileno())
-                    self.synced += self._pending
-                    self._pending = 0
+                    self._fsync(tag)
             except (OSError, ValueError) as e:
                 self.failed = f"wal append failed: {e}"
                 raise WALWriteError(self.failed) from e
@@ -199,15 +207,21 @@ class WriteAheadLog:
         crash_point("post_wal_append")
         return seq
 
+    def _fsync(self, tag=None) -> None:
+        """Make the pending records durable (caller holds the lock)."""
+        with TraceAnnotation("svf.wal.fsync", **(tag or {})):
+            self._f.flush()
+            os.fsync(self._f.fileno())
+        self.fsyncs += 1
+        self.synced += self._pending
+        self._pending = 0
+
     def sync(self) -> None:
         with self._lock:
             if self.failed:
                 raise WALWriteError(self.failed)
             try:
-                self._f.flush()
-                os.fsync(self._f.fileno())
-                self.synced += self._pending
-                self._pending = 0
+                self._fsync()
             except (OSError, ValueError) as e:
                 self.failed = f"wal sync failed: {e}"
                 raise WALWriteError(self.failed) from e

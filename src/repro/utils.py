@@ -1,11 +1,9 @@
 """Shared utilities: pytree helpers, sharding helpers, timing, rng."""
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import os
 import pathlib
-import time
 from typing import Any, Callable, Iterable
 
 import jax
@@ -51,27 +49,6 @@ def pad_to_multiple(n: int, m: int) -> int:
 
 def shape_struct(shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(tuple(shape), dtype)
-
-
-class Timer:
-    """Wall-clock timer accumulating named spans (host-side benchmarking)."""
-
-    def __init__(self):
-        self.spans: dict[str, float] = {}
-        self.counts: dict[str, int] = {}
-
-    @contextlib.contextmanager
-    def span(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            self.spans[name] = self.spans.get(name, 0.0) + dt
-            self.counts[name] = self.counts.get(name, 0) + 1
-
-    def summary(self) -> dict[str, float]:
-        return dict(self.spans)
 
 
 def block_tree(tree):

@@ -196,8 +196,9 @@ def test_fused_epoch_flush_on_interleaved_updates(tmp_path):
 def test_engine_fused_dispatch_budget_and_stats(tmp_path):
     """Engine wiring: PQ-on engines build + warm the topology tier at
     init, steady-state batches cost 3 dispatches, and ``stats()`` is the
-    single source for the acceptance metric (``dispatches_per_query``,
-    fed by the per-result counters) plus the topology hit-rate."""
+    single source for the acceptance metric
+    (``search_dispatches_per_batch``, fed by the per-result counters)
+    plus the topology hit-rate."""
     rng = np.random.default_rng(9)
     n = 500
     vecs = rng.normal(size=(n, 16)).astype(np.float32)
@@ -211,7 +212,7 @@ def test_engine_fused_dispatch_budget_and_stats(tmp_path):
         for _ in range(4):
             eng.search(q)
         st = eng.stats()
-        assert st["dispatches_per_query"] <= 3.0
+        assert st["search_dispatches_per_batch"] <= 3.0
         assert st["topo_hit_rate"] == 1.0
         assert st["topo_misses"] == 0
         assert st["bytes_per_tier"]["device_topo_rows"] > 0
@@ -238,7 +239,7 @@ def test_engine_topo_disabled_knob(tmp_path):
         q = rng.normal(size=(8, 16)).astype(np.float32)
         eng.search(q)
         st = eng.stats()
-        assert st["dispatches_per_query"] > 3
+        assert st["search_dispatches_per_batch"] > 3
         assert st["topo_hits"] == 0 and st["topo_misses"] == 0
         assert "topo_resident" not in st
     finally:

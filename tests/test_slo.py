@@ -161,7 +161,7 @@ def test_overshoot_peek_dont_admit():
     assert tier.collect(8, 1e-4, stop) == [big]
 
 
-def _ok_search(q, degrade=0):
+def _ok_search(q, degrade=0, batch=None):
     k = 4
     return (np.zeros((len(q), k), np.int64),
             np.zeros((len(q), k), np.float32))
@@ -170,7 +170,7 @@ def _ok_search(q, degrade=0):
 def test_stop_drains_queued_futures_and_rejects_new():
     gate = threading.Event()
 
-    def blocked(q, degrade=0):
+    def blocked(q, degrade=0, batch=None):
         gate.wait(5.0)
         return _ok_search(q)
 
@@ -194,7 +194,7 @@ def test_coalescer_submit_stop_stress():
     could complete twice or never. Hammer submit/stop interleavings and
     assert every future resolves (result or error) within its timeout."""
     for trial in range(4):
-        def slow(q, degrade=0):
+        def slow(q, degrade=0, batch=None):
             time.sleep(0.002)
             return _ok_search(q)
 

@@ -207,7 +207,7 @@ def test_coalescer_demux_exact():
     and every request gets exactly its own rows back."""
     calls = []
 
-    def search_fn(qs):
+    def search_fn(qs, batch=None):
         calls.append(len(qs))
         return qs[:, :1].astype(np.int32), qs[:, :1]
 
@@ -231,7 +231,7 @@ def test_coalescer_adaptive_window_shrinks_when_idle():
     """Uncoalesced dispatches shrink the window toward the floor so a
     lone caller's p50 converges to the direct-call latency; merged ones
     grow it (bounded)."""
-    co = CoalescingScheduler(lambda qs: (qs, qs), max_batch=8,
+    co = CoalescingScheduler(lambda qs, batch=None: (qs, qs), max_batch=8,
                              max_window=2e-3, min_window=5e-5)
     co.window = 2e-3
     q = np.zeros((1, 4), np.float32)
@@ -243,7 +243,7 @@ def test_coalescer_adaptive_window_shrinks_when_idle():
 
 
 def test_coalescer_propagates_errors():
-    def boom(qs):
+    def boom(qs, batch=None):
         raise RuntimeError("executor failed")
 
     co = CoalescingScheduler(boom)
